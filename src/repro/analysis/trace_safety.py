@@ -11,11 +11,13 @@ the single implementation those tests (and the CI gate) call:
 
 * ``scan_carry_count(p)`` — the actual ``num_carry`` of the engine's
   hot scan for ``SimParams`` ``p`` (asserting there IS exactly one);
-* ``expected_scan_carries(p)`` — the budgeted count: the frozen
-  27-entry engine carry contract (:data:`ENGINE_CARRY_KEYS`) + the
-  protocol's bank/core state leaves + the feature deltas (+1 telemetry,
-  +3 faults, +2 holder-kill mode, +3 watchdog, +1 hierarchical
-  topology);
+* ``expected_scan_carries(p)`` — the budgeted count of WRITTEN
+  carries: the frozen 27-entry engine carry contract
+  (:data:`ENGINE_CARRY_KEYS`) + the protocol's bank/core state leaves +
+  the feature deltas (+1 telemetry, +3 faults, +2 holder-kill mode, +3
+  watchdog, +1 hierarchical topology), less the leaves this config
+  never writes (:func:`read_only_carries`) — ``lax.scan`` moves a carry
+  its body passes through unchanged out of the loop as a constant;
 * ``scatter_count(p)`` — scatter-family ops inside the scan body,
   checked against each protocol's ``contract.max_hot_scatters`` budget
   (a regression reintroducing n-lane scatters into the hot path fails
@@ -33,11 +35,13 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.analysis.report import Finding, PassReport
 from repro.core import sim
 from repro.core import sweep
 from repro.core.protocols import registry as proto_registry
+from repro.core.protocols.base import Ctx
 from repro.core.topologies import registry as topo_registry
 from repro.faults import FaultPlan
 
@@ -58,6 +62,9 @@ FAULTS_CARRIES = 3               # faults_injected, halt_cyc, last_ret
 HOLDER_KILL_CARRIES = 2          # kmask, kleft
 WATCHDOG_CARRIES = 3             # wd_srv, wd_own, recoveries
 TOPO_CARRIES = 1                 # hops counter (hierarchical topologies)
+
+#: the Fig. 5 worker carries, which a worker-free trace never writes
+WORKER_CARRY_KEYS: Tuple[str, ...] = ("w_tmr", "w_served")
 
 #: ys stacked per cycle when record_trace is on (step/wait/state/qlen)
 TRACE_YS = 4
@@ -112,11 +119,57 @@ def scan_carry_count(p: sim.SimParams) -> int:
     return int(eqns[0].params["num_carry"])
 
 
+def _scan_handler_writes_polls(p: sim.SimParams) -> bool:
+    """Does the protocol's scan-path handler (``on_access``) ever update
+    the poll counter?  Probed by tracing the handler alone over one
+    all-idle cycle: an untouched counter comes back as the very input."""
+    proto = proto_registry.get(p.protocol)
+    n, a = p.n_cores, p.n_addrs
+    q_cap = proto.q_cap(p, n)
+    written = []
+
+    def probe(polls):
+        def i32(*s):
+            return jnp.zeros(s, jnp.int32)
+
+        def off(*s):
+            return jnp.zeros(s, bool)
+
+        ctx = Ctx(
+            p=sim._resolve(p), n=n, a=a, q_cap=q_cap, is_acq=off(n),
+            is_rel=off(n), wa=i32(n), wc=jnp.arange(n, dtype=jnp.int32),
+            ba=jnp.arange(a, dtype=jnp.int32), win_core=i32(a),
+            acq_b=off(a), rel_b=off(a), mod_dur=i32(n))
+        cs = dict(st=i32(n), tmr=i32(n), nxt=i32(n), polls=polls,
+                  msgs=i32(), **proto.init_core_state(p, n))
+        cs, _ = proto.on_access(ctx, cs,
+                                proto.init_bank_state(p, a, n, q_cap))
+        written.append(cs["polls"] is not polls)
+        return cs["polls"]
+
+    jax.make_jaxpr(probe)(jnp.zeros((), jnp.int32))
+    return written[0]
+
+
+def read_only_carries(p: sim.SimParams) -> Tuple[str, ...]:
+    """Contract leaves the scan body of ``p`` passes through unchanged:
+    the worker pair on a worker-free trace (the engine statically elides
+    their updates), and ``polls`` when the protocol's handler never
+    polls.  ``lax.scan`` hoists such leaves out of the loop, so they are
+    not carries of the traced scan."""
+    ro = WORKER_CARRY_KEYS if p.n_workers == 0 else ()
+    if not _scan_handler_writes_polls(p):
+        ro += ("polls",)
+    return ro
+
+
 def expected_scan_carries(p: sim.SimParams) -> int:
-    """The carry budget for ``p`` from the frozen engine contract plus
-    the protocol's declared state and the feature gates — computed
-    WITHOUT tracing the engine, so a drift between this formula and the
-    real scan is always a reportable finding."""
+    """The written-carry budget for ``p`` from the frozen engine
+    contract plus the protocol's declared state and the feature gates,
+    less :func:`read_only_carries` — computed WITHOUT tracing the
+    engine, so a drift between this formula and the real scan (a stray
+    written carry, or a contract key gone missing) is always a
+    reportable finding."""
     proto = proto_registry.get(p.protocol)
     n, a = p.n_cores, p.n_addrs
     q_cap = proto.q_cap(p, n)
@@ -136,7 +189,7 @@ def expected_scan_carries(p: sim.SimParams) -> int:
             cnt += HOLDER_KILL_CARRIES
         if fp.watchdog_cyc > 0 and proto.held(bank) is not None:
             cnt += WATCHDOG_CARRIES
-    return cnt
+    return cnt - len(read_only_carries(p))
 
 
 _SCATTER_PREFIX = "scatter"

@@ -127,7 +127,7 @@ def _percentile_from_waits(waits: np.ndarray, q: float) -> float:
 
 
 def trace_latency_hist(res: Dict[str, np.ndarray],
-                       use_kernel: bool = True) -> np.ndarray:
+                       interpret: Optional[bool] = None) -> np.ndarray:
     """Exact-trace completion-latency histogram on the engine's geometric
     bins — the recorded per-completion waits (``record_trace=True``)
     folded onto the same ``LAT_BINS``/``LAT_SUB`` geometry as the
@@ -135,9 +135,10 @@ def trace_latency_hist(res: Dict[str, np.ndarray],
     comparable (equal, in fact: both count every retirement once —
     ``tests/test_kernels.py`` pins this against a live engine run).
 
-    The commit goes through the ``colibri_scatter`` Pallas kernel (the
-    paper's retry-free scatter-RMW counting its own latencies);
-    ``use_kernel=False`` uses a plain ``np.bincount``.
+    With ``interpret`` given, the commit goes through the
+    ``colibri_scatter`` Pallas kernel (the paper's retry-free scatter-RMW
+    counting its own latencies), interpreted or compiled as it says;
+    ``None`` uses a plain ``np.bincount``.
     """
     tw = np.asarray(res["trace_wait"])
     waits = tw[tw >= 0]
@@ -148,10 +149,10 @@ def trace_latency_hist(res: Dict[str, np.ndarray],
     bkt = np.clip((LAT_SUB * np.log2(
         waits.astype(np.float32) + np.float32(1.0))).astype(np.int32),
         0, LAT_BINS - 1)
-    if not use_kernel:
+    if interpret is None:
         return np.bincount(bkt, minlength=LAT_BINS).astype(np.int32)
     from repro.kernels.colibri_scatter import colibri_histogram
-    return np.asarray(colibri_histogram(bkt, LAT_BINS))
+    return np.asarray(colibri_histogram(bkt, LAT_BINS, interpret=interpret))
 
 
 def latency_percentiles(res: Dict[str, np.ndarray]) -> Dict[str, float]:

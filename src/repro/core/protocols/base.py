@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Tuple
 
+import jax
 import jax.numpy as jnp
 
 # core states (BARWAIT: parked at a workload barrier, polling-free)
@@ -103,6 +104,56 @@ def mset(arr, idx, mask, val):
     (out-of-bounds index). Avoids duplicate-index races."""
     oob = jnp.full_like(idx, arr.shape[0])
     return arr.at[jnp.where(mask, idx, oob)].set(val, mode="drop")
+
+
+# ---- dense gather/scatter stand-ins for ``fused_access`` -------------------
+# The TPU kernel compiler (Mosaic) lowers neither scatters nor gathers by a
+# computed index, nor a reshape of a bool vector, so the kernel-fusable
+# forms restate every indexed read or write as a one-hot select over a
+# static iota, with masks folded into the index (-1 selects nothing).  Each
+# bank lane reads and writes only its own row(s), so a select picks at most
+# one element.
+
+def take_cols(buf, col):
+    """``buf[arange(r), col]`` for an ``(r, c)`` buffer: each row's
+    element at its own column index."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1) == col[:, None]
+    if buf.dtype == jnp.bool_:
+        return jnp.sum(jnp.where(hit & buf, 1, 0), axis=1) > 0
+    return jnp.sum(jnp.where(hit, buf, 0), axis=1).astype(buf.dtype)
+
+
+def put_cols(buf, col, mask, val):
+    """``buf.at[arange(r), col].set(val)`` on the rows where ``mask``;
+    ``val`` is a scalar or one value per row (a Python bool for a bool
+    ``buf``: Mosaic lowers no bool constant, so it becomes a bit op)."""
+    hit = (jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+           == jnp.where(mask, col, -1)[:, None])
+    if buf.dtype == jnp.bool_:
+        return (buf | hit) if val else (buf & ~hit)
+    val = jnp.asarray(val, buf.dtype)
+    return jnp.where(hit, val[:, None] if val.ndim else val, buf)
+
+
+def onehot_rows(idx, mask, size):
+    """``(len(idx), size)`` bool: lane ``i`` selects row ``idx[i]`` of a
+    length-``size`` array when ``mask[i]`` (distinct lanes must select
+    distinct rows)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], size), 1)
+    return rows == jnp.where(mask, idx, -1)[:, None]
+
+
+def take_rows(x, idx):
+    """``x[idx]`` for a 1-D int ``x``."""
+    hit = (jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], x.shape[0]), 1)
+           == idx[:, None])
+    return jnp.sum(jnp.where(hit, x[None, :], 0), axis=1).astype(x.dtype)
+
+
+def scatter_rows(hit, val):
+    """Per-row value a one-hot ``hit`` (from :func:`onehot_rows`) sends:
+    ``val[i]`` at row ``idx[i]``, 0 on rows no lane selects."""
+    return jnp.sum(jnp.where(hit, val[:, None], 0), axis=0)
 
 
 @dataclasses.dataclass

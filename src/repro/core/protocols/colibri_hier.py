@@ -26,7 +26,9 @@ import jax.numpy as jnp
 from repro.core.protocols.base import (MOD, NXT_MOD, NXT_WORK_DONE, OUT_DONE,
                                        OUT_EVICT, OUT_GRANT, OUT_NONE,
                                        OUT_REDELIVER, OUT_SLEEP, RESP, SLEEP,
-                                       Contract, FusedOut, Protocol)
+                                       Contract, FusedOut, Protocol,
+                                       onehot_rows, put_cols, scatter_rows,
+                                       take_cols, take_rows)
 from repro.core.protocols.registry import register
 
 
@@ -175,8 +177,10 @@ class ColibriHier(Protocol):
     def fused_access(self, fx, bank):
         # the on_access dense bank updates, restated block-locally: bank
         # ids come from a local iota over this block's lanes (the flat
-        # (addr, group) queue ids follow from it), and the per-core
-        # grant/enqueue/release effects become OUT_* codes.
+        # (addr, group) queue ids follow from it), indexed reads and
+        # writes are one-hot selects (the kernel lowers no gather or
+        # scatter), and the per-core grant/enqueue/release effects become
+        # OUT_* codes.
         G, gsz, cap_l = self._geom(fx.p, fx.n)
         lqbuf, lqhead, lqlen = bank["lqbuf"], bank["lqhead"], bank["lqlen"]
         ggq, gqhead, gqlen = bank["ggq"], bank["gqhead"], bank["gqlen"]
@@ -187,7 +191,6 @@ class ColibriHier(Protocol):
         ba = jnp.arange(a, dtype=jnp.int32)
         g_b = jnp.minimum(jnp.minimum(fx.win, fx.n - 1) // gsz, G - 1)
         lq_b = ba * G + g_b
-        oob_a, oob_lq = a, a * G
 
         # ---- acquire ----
         idle_b = cur_grp < 0
@@ -195,40 +198,43 @@ class ColibriHier(Protocol):
         cur_grp = jnp.where(grant_b, g_b, cur_grp)
         turn_srv = jnp.where(grant_b, 0, turn_srv)
         enq_b = fx.acq_b & ~idle_b
-        slot_b = (lqhead[lq_b] + lqlen[lq_b]) % cap_l
-        put_lq = jnp.where(enq_b, lq_b, oob_lq)
-        lqbuf = lqbuf.at[put_lq, slot_b].set(fx.win, mode="drop")
-        lqlen = lqlen.at[put_lq].add(1, mode="drop")
+        len_b = take_rows(lqlen, lq_b)
+        slot_b = (take_rows(lqhead, lq_b) + len_b) % cap_l
+        put_lq = onehot_rows(lq_b, enq_b, a * G)
+        put_r = scatter_rows(put_lq, jnp.ones_like(lq_b))   # 0/1 per row
+        lqbuf = put_cols(lqbuf, scatter_rows(put_lq, slot_b), put_r > 0,
+                         scatter_rows(put_lq, fx.win))
+        lqlen = lqlen + put_r
+        # a lane writes only its own bank's queue rows, so its queue
+        # length after the enqueue is its own read plus its own put
+        len_b = len_b + enq_b
         msgs = enq_b.astype(jnp.int32)           # intra-cluster SuccUpdate
-        reg_b = enq_b & (cur_grp != g_b) & ~g_inq[ba, g_b]
+        reg_b = enq_b & (cur_grp != g_b) & ~take_cols(g_inq, g_b)
         gslot_b = (gqhead + gqlen) % G
-        reg_a = jnp.where(reg_b, ba, oob_a)
-        ggq = ggq.at[reg_a, gslot_b].set(g_b, mode="drop")
+        ggq = put_cols(ggq, gslot_b, reg_b, g_b)
         gqlen = gqlen + reg_b
-        g_inq = g_inq.at[reg_a, g_b].set(True, mode="drop")
+        g_inq = put_cols(g_inq, g_b, reg_b, True)
         msgs = msgs + 2 * reg_b                  # global registration RT
 
         # ---- release ----
         srv_b = turn_srv + 1
         exhausted_b = fx.rel_b & (srv_b >= gsz) & (gqlen > 0)
-        more_local_b = fx.rel_b & (lqlen[lq_b] > 0) & ~exhausted_b
+        more_local_b = fx.rel_b & (len_b > 0) & ~exhausted_b
         wake_grp = jnp.where(more_local_b, g_b, wake_grp)
         wake_tmr = jnp.where(more_local_b, self.local_delay, wake_tmr)
         msgs = msgs + more_local_b               # intra-cluster wake
         turn_srv = jnp.where(more_local_b, srv_b, turn_srv)
-        re_reg_b = fx.rel_b & (lqlen[lq_b] > 0) & exhausted_b
+        re_reg_b = fx.rel_b & (len_b > 0) & exhausted_b
         tail_b = (gqhead + gqlen) % G
-        re_reg_a = jnp.where(re_reg_b, ba, oob_a)
-        ggq = ggq.at[re_reg_a, tail_b].set(g_b, mode="drop")
+        ggq = put_cols(ggq, tail_b, re_reg_b, g_b)
         gqlen = gqlen + re_reg_b
-        g_inq = g_inq.at[re_reg_a, g_b].set(True, mode="drop")
+        g_inq = put_cols(g_inq, g_b, re_reg_b, True)
         msgs = msgs + 2 * re_reg_b               # re-registration RT
-        end_turn_b = fx.rel_b & ((lqlen[lq_b] == 0) | exhausted_b)
+        end_turn_b = fx.rel_b & ((len_b == 0) | exhausted_b)
         have_next_b = end_turn_b & (gqlen > 0)
-        next_g_b = ggq[ba, gqhead]
+        next_g_b = take_cols(ggq, gqhead)
         cur_grp = jnp.where(have_next_b, next_g_b, cur_grp)
-        g_inq = g_inq.at[jnp.where(have_next_b, ba, oob_a), next_g_b].set(
-            False, mode="drop")
+        g_inq = put_cols(g_inq, next_g_b, have_next_b, False)
         gqhead = jnp.where(have_next_b, (gqhead + 1) % G, gqhead)
         gqlen = gqlen - have_next_b
         wake_grp = jnp.where(have_next_b, next_g_b, wake_grp)

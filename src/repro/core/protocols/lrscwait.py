@@ -14,7 +14,7 @@ from repro.core.protocols.base import (NXT_BACKOFF, NXT_MOD, NXT_WORK_DONE,
                                        OUT_DONE, OUT_FAIL, OUT_GRANT,
                                        OUT_NONE, OUT_SLEEP, RESP, SLEEP,
                                        Contract, FifoQueueRecovery, FusedOut,
-                                       Protocol)
+                                       Protocol, put_cols)
 from repro.core.protocols.registry import register
 
 
@@ -94,7 +94,6 @@ class LrscWait(FifoQueueRecovery, Protocol):
     def fused_access(self, fx, bank):
         q_cap = fx.q_cap
         qbuf, qhead, qlen = bank["qbuf"], bank["qhead"], bank["qlen"]
-        ba = jnp.arange(qbuf.shape[0], dtype=jnp.int32)   # block-local
         empty_b = qlen == 0
         full_b = qlen >= q_cap
         grant_b = fx.acq_b & empty_b
@@ -102,8 +101,7 @@ class LrscWait(FifoQueueRecovery, Protocol):
         rej_b = fx.acq_b & full_b                # finite-q immediate fail
         put_b = fx.acq_b & ~full_b
         slot_b = (qhead + qlen) % q_cap
-        qbuf = qbuf.at[jnp.where(put_b, ba, qbuf.shape[0]), slot_b].set(
-            fx.win, mode="drop")
+        qbuf = put_cols(qbuf, slot_b, put_b, fx.win)
         kind = jnp.where(
             grant_b, OUT_GRANT,
             jnp.where(enq_b, OUT_SLEEP,

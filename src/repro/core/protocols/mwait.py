@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from repro.core.protocols.base import (NXT_MOD, NXT_WORK_DONE, OUT_DONE,
                                        OUT_GRANT, OUT_NONE, OUT_SLEEP, RESP,
                                        SLEEP, Contract, FifoQueueRecovery,
-                                       FusedOut, Protocol)
+                                       FusedOut, Protocol, put_cols)
 from repro.core.protocols.registry import register
 
 
@@ -73,13 +73,11 @@ class MwaitLock(FifoQueueRecovery, Protocol):
     def fused_access(self, fx, bank):
         q_cap = fx.q_cap
         qbuf, qhead, qlen = bank["qbuf"], bank["qhead"], bank["qlen"]
-        ba = jnp.arange(qbuf.shape[0], dtype=jnp.int32)   # block-local
         empty_b = qlen == 0
         grant_b = fx.acq_b & empty_b
         enq_b = fx.acq_b & ~empty_b
         slot_b = (qhead + qlen) % q_cap
-        qbuf = qbuf.at[jnp.where(fx.acq_b, ba, qbuf.shape[0]), slot_b].set(
-            fx.win, mode="drop")
+        qbuf = put_cols(qbuf, slot_b, fx.acq_b, fx.win)
         kind = jnp.where(
             grant_b, OUT_GRANT,
             jnp.where(enq_b, OUT_SLEEP,

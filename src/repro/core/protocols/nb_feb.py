@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from repro.core.protocols.base import (NXT_MOD, NXT_WORK_DONE, OUT_DONE,
                                        OUT_GRANT, OUT_NONE, OUT_SLEEP, RESP,
                                        SLEEP, Contract, FifoQueueRecovery,
-                                       FusedOut, Protocol)
+                                       FusedOut, Protocol, put_cols)
 from repro.core.protocols.registry import register
 
 
@@ -102,14 +102,12 @@ class NbFeb(FifoQueueRecovery, Protocol):
         q_cap = fx.q_cap
         feb = bank["feb"]
         qbuf, qhead, qlen = bank["qbuf"], bank["qhead"], bank["qlen"]
-        ba = jnp.arange(qbuf.shape[0], dtype=jnp.int32)   # block-local
         grant_b = fx.acq_b & feb
         enq_b = fx.acq_b & ~feb
         put_b = fx.acq_b
         slot_b = (qhead + qlen) % q_cap
-        qbuf = qbuf.at[jnp.where(put_b, ba, qbuf.shape[0]), slot_b].set(
-            fx.win, mode="drop")
-        feb = jnp.where(fx.acq_b, False, feb)
+        qbuf = put_cols(qbuf, slot_b, put_b, fx.win)
+        feb = feb & ~fx.acq_b          # (Mosaic lowers no bool constant)
         kind = jnp.where(
             grant_b, OUT_GRANT,
             jnp.where(enq_b, OUT_SLEEP,
@@ -119,7 +117,7 @@ class NbFeb(FifoQueueRecovery, Protocol):
         qhead = jnp.where(fx.rel_b, (qhead + 1) % q_cap, qhead)
         qlen = qlen + put_b - fx.rel_b
         pend_b = fx.rel_b & (qlen > 0)
-        feb = jnp.where(fx.rel_b & (qlen == 0), True, feb)
+        feb = feb | (fx.rel_b & (qlen == 0))
         wake_tmr = jnp.where(pend_b, self.wake_delay(fx.p),
                              bank["wake_tmr"])
         bank = dict(bank, feb=feb, qbuf=qbuf, qhead=qhead, qlen=qlen,

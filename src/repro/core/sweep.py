@@ -35,8 +35,10 @@ Executor shape (the hot path behind every figure):
   sharded across devices (``NamedSharding``); the single-device path is
   byte-for-byte the old behaviour.
 * **Persistent compilation cache** — :func:`enable_persistent_cache`
-  points jax at an on-disk cache so repeated benchmark runs skip
-  recompiles entirely (``benchmarks/run.py`` calls it at startup).
+  points jax at an on-disk cache (``$JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache``) so repeated benchmark runs skip recompiles
+  entirely (``benchmarks/run.py`` and ``chip_smoke.py`` call it at
+  startup).
 
 ``n_addrs`` is traced too: configs bucket by the next power of two of
 their address count (``_bucket_a``), the engine allocates banks for the
@@ -65,6 +67,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.sim import (DYN_FIELDS, _DENSE_BANK_ELTS, SimParams,
                             derive_metrics, simulate)
@@ -82,28 +85,29 @@ STATIC_FIELDS = ("protocol", "workload", "n_cores", "cycles", "q_slots",
 DEFAULT_MAX_BATCH = 256
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> str:
+#: the cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset: fixed
+#: inside the checkout, because the path is part of every cache key
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_persistent_cache() -> str:
     """Enable jax's on-disk compilation cache (idempotent).
 
     Repeated benchmark runs re-trace the same engine fingerprints; with
     the cache enabled the XLA compile step is skipped on every run after
-    the first.  ``path`` defaults to ``$REPRO_CACHE_DIR`` or
-    ``~/.cache/lrscwait-repro/jax``.  Returns the cache directory.
+    the first.  The directory is ``$JAX_COMPILATION_CACHE_DIR`` when that
+    is set (placed from outside, and no other directory is used), else
+    :data:`DEFAULT_CACHE_DIR`.  Returns the cache directory.
     """
-    path = path or os.environ.get(
-        "REPRO_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "lrscwait-repro",
-                     "jax"))
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     # cache even fast/small compiles — the sweep fingerprints are many
     # and individually cheap, but a full benchmark run has dozens
-    for opt, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                     ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(opt, val)
-        except AttributeError:          # option not in this jax version
-            pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
 
 
@@ -136,12 +140,23 @@ def _finite_metrics(res) -> bool:
     return True
 
 
-@partial(jax.jit, static_argnums=(0, 2))
-def _sweep_group(rep: SimParams, dyn: Dict[str, jnp.ndarray], batch: int):
+@partial(jax.jit, static_argnums=(0, 2, 3))
+def _sweep_group(rep: SimParams, dyn: Dict[str, jnp.ndarray], batch: int,
+                 mesh=None):
     # `batch` sizes the engine's dense-vs-scatter arbitration choice for
     # the vmapped working set; it is already implied by dyn's shapes, so
     # making it static adds no extra compiles
-    return jax.vmap(lambda d: simulate(rep, dyn=d, batch=batch))(dyn)
+    def run(d):
+        return jax.vmap(lambda x: simulate(rep, dyn=x, batch=batch))(d)
+    if mesh is None:
+        return run(dyn)
+    # a sharded batch runs as one shard_map over the mesh: each device
+    # simulates its own slice of points.  The compiler cannot partition
+    # the engine_step kernel (a Mosaic custom call) by itself, and the
+    # kernel's out_shape carries no varying-axes annotation to check.
+    spec = PartitionSpec("batch")
+    return jax.shard_map(run, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(dyn)
 
 
 def _batch_sharding():
@@ -150,7 +165,6 @@ def _batch_sharding():
     devs = jax.devices()
     if len(devs) <= 1:
         return None, 1
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
     mesh = Mesh(np.asarray(devs), ("batch",))
     return NamedSharding(mesh, PartitionSpec("batch")), len(devs)
 
@@ -329,7 +343,9 @@ def sweep_iter(configs: Sequence[SimParams],
             cache_before = _sweep_group._cache_size() \
                 if report is not None else 0
             try:
-                out = _sweep_group(crep, dyn, len(padded))
+                out = _sweep_group(crep, dyn, len(padded),
+                                   None if sharding is None
+                                   else sharding.mesh)
             except Exception:        # noqa: BLE001 — fenced by design
                 # poisoned at trace/compile time: fence it now, the
                 # stream keeps flowing
@@ -348,7 +364,8 @@ def sweep_iter(configs: Sequence[SimParams],
                            f"{crep.cycles}cyc"),
                     points=len(part), batch=len(padded),
                     compile_s=time.perf_counter() - t0, execute_s=0.0,
-                    compiled=compiled)
+                    compiled=compiled,
+                    devices=len(jax.tree.leaves(out)[0].sharding.device_set))
                 rec = report.chunks[-1]
             pending.append((part, out, rec))
             if len(pending) >= window:

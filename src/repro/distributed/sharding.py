@@ -20,18 +20,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-proof ``shard_map``: top-level ``jax.shard_map`` where it
-    exists, the experimental API (with its ``check_rep`` spelling of the
-    replication check) on older jax."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-
 Params = Dict[str, Any]
 
 

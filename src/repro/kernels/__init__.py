@@ -3,20 +3,11 @@
 Each kernel package provides:
   * ``kernel.py`` — ``pl.pallas_call`` with explicit BlockSpec VMEM tiling,
     written for TPU (MXU-aligned tiles, fp32 accumulation);
-  * ``ops.py``    — the jit'd public wrapper (interpret=True on CPU);
+  * ``ops.py``    — the public wrapper;
   * ``ref.py``    — the pure-jnp oracle the kernel is validated against.
 
-This container is CPU-only: kernels execute via ``interpret=True`` (the
-kernel body runs in Python on CPU) for correctness; on real TPU the same
-code lowers to Mosaic. Model graphs use the pure-JAX path for the dry-run
-(XLA:CPU cannot lower TPU pallas_call) and switch with ``use_pallas=True``.
+Every op takes ``interpret`` explicitly, with no default: ``True`` runs
+the kernel body in the Pallas interpreter (the CPU tests), ``False``
+compiles it for the device.  Nothing picks interpret mode from the
+visible backend, so a run on the chip never interprets by accident.
 """
-
-
-def on_tpu() -> bool:
-    import jax
-    return jax.default_backend() == "tpu"
-
-
-def interpret_mode() -> bool:
-    return not on_tpu()

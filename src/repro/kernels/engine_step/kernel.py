@@ -24,6 +24,12 @@ Per-tile partial outputs (histogram rows, [polls, msgs, lat_max] stat
 rows) are reduced OUTSIDE the kernel — cross-tile accumulation through a
 shared output block is exactly the pattern that breaks on parallel
 grids.
+
+The body lowers for the TPU (Mosaic): no gather or scatter by a computed
+index (the winner's per-core values ride the arbitration merge as
+one-hot selects; protocols use the dense helpers of
+``core.protocols.base``), no bool in memory, and every 1-D operand as a
+``(1, w)`` row.  ``tests/test_tpu_compile.py`` compiles it for a v5e.
 """
 from __future__ import annotations
 
@@ -43,7 +49,7 @@ _N_STATS = 3
 
 
 def _kernel(*refs, proto, p, n, block_a, block_n, q_cap, cycles,
-            core_names, bank_names, xset_names):
+            core_names, bank_names, xset_names, bool_banks, row_banks):
     n_core, n_bank, n_xset = len(core_names), len(bank_names), len(xset_names)
     nin = 6 + n_core + n_bank
     scal_ref, cand_ref, rot_ref, addr_ref, phase_ref, acq_ref = refs[:6]
@@ -57,7 +63,9 @@ def _kernel(*refs, proto, p, n, block_a, block_n, q_cap, cycles,
                        outs[4 + n_bank + n_xset:4 + n_bank + 2 * n_xset]))
     stats_ref, hist_ref = outs[-2:]
 
-    scal = scal_ref[...]
+    # 1-D operands travel as (1, w) rows (see fused_step_call): read row
+    # 0, write through a leading unit axis
+    scal = scal_ref[0]
     cyc, shift, lat = scal[0], scal[1], scal[2]
     # global bank ids of this tile's lanes
     bl = (pl.program_id(0) * block_a
@@ -67,55 +75,75 @@ def _kernel(*refs, proto, p, n, block_a, block_n, q_cap, cycles,
     # Running (stamp, rot) pair per bank lane; merging a chunk keeps the
     # smaller stamp, and on stamp ties the smaller rot — associative, so
     # chunk order never matters and the result equals the global
-    # lexicographic min (= ref.py's one-shot dense min).
+    # lexicographic min (= ref.py's one-shot dense min).  The winner's
+    # per-core values (phase, acquire stamp, the protocol's core fields)
+    # ride along: each chunk picks its own winner's values by a one-hot
+    # select (rot is a permutation, so at most one lane per bank), and the
+    # merge keeps them with the pair.  Mosaic lowers no gather by a
+    # computed index, and this never builds a (block_a, n) temporary.
+    gat_refs = (phase_ref, acq_ref) + tuple(core_refs[f] for f in core_names)
+
     def merge(i, carry):
-        run_cyc, run_rot = carry
+        run_cyc, run_rot, run_vals = carry
         sl = pl.ds(i * block_n, block_n)
-        cand, rot, adr = cand_ref[sl], rot_ref[sl], addr_ref[sl]
+        cand, rot, adr = cand_ref[0, sl], rot_ref[0, sl], addr_ref[0, sl]
         m = adr[None, :] == bl[:, None]                # (block_a, block_n)
         c2 = jnp.where(m, cand[None, :], _BIG)
         t_cyc = jnp.min(c2, axis=1)
         tie = (c2 == t_cyc[:, None]) & (c2 != _BIG)
-        t_rot = jnp.min(jnp.where(tie, rot[None, :], _BIG), axis=1)
-        better = t_cyc < run_cyc
-        same = t_cyc == run_cyc
-        run_rot = jnp.where(better, t_rot,
-                            jnp.where(same, jnp.minimum(run_rot, t_rot),
-                                      run_rot))
-        return jnp.minimum(run_cyc, t_cyc), run_rot
+        r2 = jnp.where(tie, rot[None, :], _BIG)
+        t_rot = jnp.min(r2, axis=1)
+        sel = tie & (r2 == t_rot[:, None])             # chunk winner lane
+        better = (t_cyc < run_cyc) | ((t_cyc == run_cyc) & (t_rot < run_rot))
+        run_vals = tuple(
+            jnp.where(better,
+                      jnp.sum(jnp.where(sel, r[0, sl][None, :], 0), axis=1),
+                      v)
+            for r, v in zip(gat_refs, run_vals))
+        return (jnp.where(better, t_cyc, run_cyc),
+                jnp.where(better, t_rot, run_rot), run_vals)
 
+    zeros = jnp.zeros((block_a,), jnp.int32)
     init = (jnp.full((block_a,), _BIG, jnp.int32),
-            jnp.full((block_a,), _BIG, jnp.int32))
-    best_cyc, best_rot = jax.lax.fori_loop(0, n // block_n, merge, init)
+            jnp.full((block_a,), _BIG, jnp.int32),
+            (zeros,) * len(gat_refs))
+    best_cyc, best_rot, (phase_w, acq_w, *core_w) = jax.lax.fori_loop(
+        0, n // block_n, merge, init)
     valid = best_cyc != _BIG
     win = jnp.where(valid, (best_rot - shift) % n, n).astype(jnp.int32)
-    wcs = jnp.minimum(win, n - 1)                      # gather-safe
 
     # ---- stage 2: protocol dense bank update over this tile
-    phase_w = phase_ref[...][wcs]
     acq_b = valid & (phase_w == P_ACQ)
     rel_b = valid & (phase_w == P_REL)
     fx = FusedCtx(p=_param_ns(p, lat), n=n, a=block_a, q_cap=q_cap,
                   win=win, acq_b=acq_b, rel_b=rel_b,
-                  core={f: core_refs[f][...][wcs] for f in core_names})
+                  core=dict(zip(core_names, core_w)))
+    # bool arrays cross the kernel boundary as int32 (Mosaic has no
+    # bool memory layout); the protocol sees its own dtypes
+    bank = {k: bank_refs[k][0] if k in row_banks else bank_refs[k][...]
+            for k in bank_names}
     bank2, fo = proto.fused_access(
-        fx, {k: bank_refs[k][...] for k in bank_names})
+        fx, {k: (v != 0) if k in bool_banks else v for k, v in bank.items()})
 
-    valid_ref[...] = valid
-    win_ref[...] = win
-    kind_ref[...] = fo.kind
-    tmr_ref[...] = fo.tmr
+    def put(ref, v, row=True):
+        v = v.astype(jnp.int32)
+        ref[...] = v[None, :] if row else v
+
+    put(valid_ref, valid)
+    put(win_ref, win)
+    put(kind_ref, fo.kind)
+    put(tmr_ref, fo.tmr)
     for k in bank_names:
-        bank_out[k][...] = bank2[k]
+        put(bank_out[k], bank2[k], k in row_banks)
     for f in xset_names:
         val, msk = fo.xset[f]
-        xv_refs[f][...] = val.astype(jnp.int32)
-        xm_refs[f][...] = msk
+        put(xv_refs[f], val)
+        put(xm_refs[f], msk)
 
     # ---- stage 3: completion-latency histogram row for this tile
     done_cyc = cyc + jnp.maximum(fo.tmr, 1)
     fut = (fo.kind == OUT_DONE) & (done_cyc < cycles)
-    lat_b = done_cyc - acq_ref[...][wcs]
+    lat_b = done_cyc - acq_w
     lbkt = jnp.clip((LAT_SUB * jnp.log2(
         lat_b.astype(jnp.float32) + 1.0)).astype(jnp.int32),
         0, LAT_BINS - 1)
@@ -132,7 +160,7 @@ def _kernel(*refs, proto, p, n, block_a, block_n, q_cap, cycles,
 
 def fused_step_call(proto, p, bank, *, cand_cyc, rot, addr, phase,
                     acq_start, core, cyc, shift, lat, n, a, q_cap, cycles,
-                    block_a=None, block_n=None, interpret=True):
+                    interpret, block_a=None, block_n=None):
     """Launch the tiled kernel; same contract as ``ref.fused_step_ref``."""
     block_a = a if block_a is None else block_a
     block_n = n if block_n is None else block_n
@@ -144,58 +172,74 @@ def fused_step_call(proto, p, bank, *, cand_cyc, rot, addr, phase,
     core_names = tuple(proto.fused_core_fields)
     bank_names = tuple(sorted(bank))
     xset_names = tuple(proto.fused_xset_fields)
+    # Mosaic constraints on what crosses the kernel boundary: bool arrays
+    # travel as int32 (there is no bool memory layout), and every 1-D
+    # array as a (1, w) row, so that a block's last two dims are either
+    # the array's own or (8, 128)-aligned, also once vmap (the sweep
+    # runner) prepends a batch axis, and so that a bank tile of a row
+    # matches XLA's layout of the whole row
+    bool_banks = frozenset(k for k in bank_names
+                           if bank[k].dtype == jnp.bool_)
+    row_banks = frozenset(k for k in bank_names if bank[k].ndim == 1)
+    bank_in = [bank[k].astype(jnp.int32) if k in bool_banks else bank[k]
+               for k in bank_names]
+    bank_in = [v.reshape(1, -1) if v.ndim == 1 else v for v in bank_in]
 
-    def _const(shape):                       # same full block at every tile
-        return pl.BlockSpec(shape, lambda at: (0,) * len(shape))
+    def _const(w):                           # same full (1, w) row everywhere
+        return pl.BlockSpec((1, w), lambda at: (0, 0))
 
-    def _banked(shape):                      # leading dim is m*a -> m*block_a
-        m = shape[0] // a
+    def _banked(shape):                      # bank dim is m*a -> m*block_a
+        if shape[0] == 1:                    # a (1, m*a) row
+            return pl.BlockSpec((1, shape[1] // a * block_a),
+                                lambda at: (0, at))
         rest = tuple(shape[1:])
-        return pl.BlockSpec((m * block_a,) + rest,
+        return pl.BlockSpec((shape[0] // a * block_a,) + rest,
                             lambda at: (at,) + (0,) * len(rest))
 
     scal = jnp.stack([jnp.asarray(cyc, jnp.int32),
                       jnp.asarray(shift, jnp.int32),
-                      jnp.asarray(lat, jnp.int32)])
-    in_specs = ([_const((3,))] + [_const((n,))] * 5
-                + [_const((n,)) for _ in core_names]
-                + [_banked(bank[k].shape) for k in bank_names])
-    lane = pl.BlockSpec((block_a,), lambda at: (at,))
-    row = lambda w: pl.BlockSpec((1, w), lambda at: (at, 0))  # noqa: E731
+                      jnp.asarray(lat, jnp.int32)])[None, :]
+    in_specs = ([_const(3)] + [_const(n)] * (5 + len(core_names))
+                + [_banked(v.shape) for v in bank_in])
+    lane = _banked((1, a))
+    # per-tile partials: a (ga, 1, w) array whose squeezed block is the
+    # array's own last two dims
+    row = lambda w: pl.BlockSpec((None, 1, w),  # noqa: E731
+                                 lambda at: (at, 0, 0))
+    lanes = jax.ShapeDtypeStruct((1, a), jnp.int32)
     out_specs = ([lane] * 4
-                 + [_banked(bank[k].shape) for k in bank_names]
+                 + [_banked(v.shape) for v in bank_in]
                  + [lane] * (2 * len(xset_names))
                  + [row(_N_STATS), row(LAT_BINS)])
-    out_shape = ([jax.ShapeDtypeStruct((a,), jnp.bool_)]
-                 + [jax.ShapeDtypeStruct((a,), jnp.int32)] * 3
-                 + [jax.ShapeDtypeStruct(bank[k].shape, bank[k].dtype)
-                    for k in bank_names]
-                 + [jax.ShapeDtypeStruct((a,), jnp.int32)
-                    for _ in xset_names]
-                 + [jax.ShapeDtypeStruct((a,), jnp.bool_)
-                    for _ in xset_names]
-                 + [jax.ShapeDtypeStruct((ga, _N_STATS), jnp.int32),
-                    jax.ShapeDtypeStruct((ga, LAT_BINS), jnp.int32)])
+    out_shape = ([lanes] * 4
+                 + [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in bank_in]
+                 + [lanes] * (2 * len(xset_names))
+                 + [jax.ShapeDtypeStruct((ga, 1, _N_STATS), jnp.int32),
+                    jax.ShapeDtypeStruct((ga, 1, LAT_BINS), jnp.int32)])
     outs = pl.pallas_call(
         functools.partial(_kernel, proto=proto, p=p, n=n, block_a=block_a,
                           block_n=block_n, q_cap=q_cap, cycles=cycles,
                           core_names=core_names, bank_names=bank_names,
-                          xset_names=xset_names),
+                          xset_names=xset_names, bool_banks=bool_banks,
+                          row_banks=row_banks),
         grid=(ga,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(scal, cand_cyc, rot, addr, phase, acq_start,
-      *[core[f] for f in core_names], *[bank[k] for k in bank_names])
+    )(scal, *[x.reshape(1, n) for x in
+              (cand_cyc, rot, addr, phase, acq_start,
+               *[core[f] for f in core_names])],
+      *bank_in)
 
-    valid, win, kind, tmr = outs[:4]
+    valid, win, kind, tmr = (o[0] for o in outs[:4])
     nb, nx = len(bank_names), len(xset_names)
-    bank_new = dict(zip(bank_names, outs[4:4 + nb]))
-    xv = outs[4 + nb:4 + nb + nx]
-    xm = outs[4 + nb + nx:4 + nb + 2 * nx]
-    stats, hist = outs[-2:]
-    return dict(valid=valid, win=win, kind=kind, tmr=tmr, bank=bank_new,
+    bank_new = {k: (v != 0 if k in bool_banks else v).reshape(bank[k].shape)
+                for k, v in zip(bank_names, outs[4:4 + nb])}
+    xv = [v[0] for v in outs[4 + nb:4 + nb + nx]]
+    xm = [m[0] != 0 for m in outs[4 + nb + nx:4 + nb + 2 * nx]]
+    stats, hist = outs[-2][:, 0], outs[-1][:, 0]
+    return dict(valid=valid != 0, win=win, kind=kind, tmr=tmr, bank=bank_new,
                 xset={f: (v, m) for f, v, m in zip(xset_names, xv, xm)},
                 polls=stats[:, 0].sum(), msgs=stats[:, 1].sum(),
                 hist=hist.sum(axis=0), lat_max=stats[:, 2].max())

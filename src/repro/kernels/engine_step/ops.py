@@ -52,10 +52,12 @@ def pick_block(extent: int, pref: int) -> int:
 def fused_step(proto, p, bank: Dict, *, cand_cyc, rot, addr, phase,
                acq_start, core: Dict, cyc, shift, lat,
                n: int, a: int, q_cap: int, cycles: int,
-               interpret: bool = True, block_a=None, block_n=None,
+               interpret: bool, block_a=None, block_n=None,
                use_kernel: bool = True) -> Dict:
     """Arbitrate + protocol-update + histogram for one cycle's parked
-    requests.  See ``ref.fused_step_ref`` for the argument contract."""
+    requests.  See ``ref.fused_step_ref`` for the argument contract.
+    ``interpret`` has no default: the caller says whether the kernel runs
+    in the Pallas interpreter (CPU) or compiles for the device."""
     if not use_kernel:
         return fused_step_ref(
             proto, p, bank, cand_cyc=cand_cyc, rot=rot, addr=addr,

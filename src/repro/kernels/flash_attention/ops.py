@@ -6,14 +6,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
-@partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
+@partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                   "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128) -> jnp.ndarray:
+                    block_k: int = 128, interpret: bool) -> jnp.ndarray:
     """q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H % KV == 0.
     Returns (B, Sq, H, hd)."""
     b, sq, h, hd = q.shape
@@ -26,5 +26,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, -1, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, -1, hd)
     out = flash_attention_kernel(qf, kf, vf, causal=causal, block_q=block_q,
-                                 block_k=block_k, interpret=interpret_mode())
+                                 block_k=block_k, interpret=interpret)
     return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)

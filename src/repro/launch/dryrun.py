@@ -37,7 +37,7 @@ from repro.configs import ARCH_NAMES, SHAPES, get_config, shape_applicable
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.distributed import cache_specs, make_policy, param_specs, shardings_of
 from repro.launch import hlo_analysis as H
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.train import batch_shardings, make_train_step, opt_state_shardings
 from repro.models import build, input_specs
 from repro.models import transformer as TF
@@ -119,7 +119,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     try:
         step, args, shardings, donate = build_cell(cfg, shape, mesh, opt_cfg)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=shardings,
                               donate_argnums=donate).lower(*args)
             t_lower = time.time() - t0

@@ -6,15 +6,7 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-
-    def _axis_kw(n):
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: Auto is the only behaviour anyway
-    def _axis_kw(n):
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,16 +14,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2×16×16 = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / elastic reshape)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kw(len(shape)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
-
-def use_mesh(mesh):
-    """Version-proof ambient-mesh context: ``jax.set_mesh`` where it
-    exists, the legacy ``Mesh`` context manager otherwise."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.distributed.sharding import shard_map
 from repro.models import layers as L
 
 Params = Dict[str, Any]
@@ -264,7 +263,7 @@ def _cp_attention(policy, cfg, q, k, v, *, causal: bool, scale: float):
         return blocked_attention(qb, ke, ve, causal=causal, scale=scale,
                                  q_offset=off)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=policy.mesh,
         in_specs=(P(dp, tp, None, None), P(dp, None, None, None),
                   P(dp, None, None, None)),

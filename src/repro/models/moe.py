@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import dispatch as D
-from repro.distributed.sharding import Policy, shard_map
+from repro.distributed.sharding import Policy
 from repro.models import layers as L
 
 Params = Dict[str, Any]
@@ -180,7 +180,7 @@ def moe_apply(cfg: ModelConfig, p: Params, x, policy: Policy
     def f(x_, r_, wg_, wu_, wd_):
         return body(r_, wg_, wu_, wd_, x_)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         f, mesh=policy.mesh,
         in_specs=in_specs, out_specs=out_specs, check_vma=False)(
         x, p["router"], p["w_gate"], p["w_up"], p["w_down"])

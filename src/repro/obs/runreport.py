@@ -27,9 +27,7 @@ benchmark runs, without threading a parameter through 11 modules)::
 or explicit: ``Study.run(report=report)`` / ``Study.stream(report=...)``
 / ``sweep_iter(..., report=report)``.
 
-Persistent-cache hits are counted through ``jax.monitoring`` events
-when that API exists (jax >= 0.4.x); otherwise the counter just stays
-at 0 — the field is best-effort by design.
+Persistent-cache hits are counted through ``jax.monitoring`` events.
 """
 from __future__ import annotations
 
@@ -50,24 +48,20 @@ def _install_cache_listener() -> None:
     ``jax.monitoring`` fires a cache-hit event when an executable is
     deserialized from the on-disk cache instead of compiled.  One
     process-wide listener routes the events to whichever report is
-    currently collecting; on jax versions without the API this is a
-    silent no-op.
+    currently collecting.
     """
     global _listener_installed
     if _listener_installed:
         return
     _listener_installed = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        def _on_event(event: str, **kw: Any) -> None:
-            rep = _current
-            if rep is not None and "cache_hit" in event:
-                rep.persistent_cache_hits += 1
+    def _on_event(event: str, **kw: Any) -> None:
+        rep = _current
+        if rep is not None and "cache_hit" in event:
+            rep.persistent_cache_hits += 1
 
-        monitoring.register_event_listener(_on_event)
-    except Exception:               # pragma: no cover - best-effort
-        pass
+    monitoring.register_event_listener(_on_event)
 
 
 @dataclasses.dataclass
@@ -79,6 +73,7 @@ class ChunkRecord:
     compile_s: float      # dispatch wall: trace+compile on a miss, ~0 on hit
     execute_s: float      # materialize wall: device_get drain
     compiled: bool        # this dispatch built a new in-process executable
+    devices: int = 1      # devices the chunk's result batch is sharded over
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -110,11 +105,11 @@ class RunReport:
 
     def record_chunk(self, label: str, points: int, batch: int,
                      compile_s: float, execute_s: float,
-                     compiled: bool) -> None:
+                     compiled: bool, devices: int = 1) -> None:
         self.chunks.append(ChunkRecord(label=label, points=points,
                                        batch=batch, compile_s=compile_s,
                                        execute_s=execute_s,
-                                       compiled=compiled))
+                                       compiled=compiled, devices=devices))
 
     # ---- aggregates -----------------------------------------------------
     @property

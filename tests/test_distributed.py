@@ -29,7 +29,6 @@ from repro.configs.base import ShapeSpec
 from repro.distributed.sharding import Policy, make_policy, param_specs, shardings_of
 from repro.launch.mesh import make_mesh
 from repro.launch.train import make_train_step, batch_shardings
-from repro.launch.mesh import use_mesh
 from repro.models import build, make_batch
 from repro import optim
 
@@ -51,7 +50,7 @@ policy = make_policy(mesh, cfg)
 stepN = jax.jit(make_train_step(model, opt_cfg, policy),
                 in_shardings=(shardings_of(param_specs(params, policy), mesh),
                               None, batch_shardings(batch, policy)))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     pN, oN, mN = stepN(params, opt, batch)
 
 np.testing.assert_allclose(float(m1["loss"]), float(mN["loss"]), rtol=1e-5)
@@ -69,7 +68,7 @@ import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
 from repro.distributed.sharding import Policy, make_policy, param_specs, shardings_of
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import build, make_batch
 
 cfg = get_config("deepseek-v3-671b-smoke")
@@ -82,7 +81,7 @@ batch = make_batch(cfg, shape, jax.random.PRNGKey(1))
 l1, _ = jax.jit(lambda p, b: model.loss(p, b, Policy()))(params, batch)
 mesh = make_mesh((2, 2), ("data", "model"))
 policy = make_policy(mesh, cfg)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     lN, _ = jax.jit(lambda p, b: model.loss(p, b, policy))(params, batch)
 np.testing.assert_allclose(float(l1), float(lN), rtol=2e-4)
 print("OK moe ep == local")

@@ -38,7 +38,7 @@ def test_colibri_scatter_sweep(t, bins, d, dtype):
     k1, k2 = keys(2)
     ks = jax.random.randint(k1, (t,), 0, bins)
     vs = jax.random.normal(k2, (t, d), dtype)
-    out = colibri_scatter_add(ks, vs, bins)
+    out = colibri_scatter_add(ks, vs, bins, interpret=True)
     ref = scatter_add_ref(ks, vs.astype(jnp.float32), bins)
     tol = 1e-5 if dtype == jnp.float32 else 0.15
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -50,8 +50,10 @@ def test_colibri_scatter_block_shapes():
     k1, k2 = keys(2)
     ks = jax.random.randint(k1, (777,), 0, 50)
     vs = jax.random.normal(k2, (777, 4))
-    a = colibri_scatter_add(ks, vs, 50, block_t=128, block_bins=32)
-    b = colibri_scatter_add(ks, vs, 50, block_t=512, block_bins=128)
+    a = colibri_scatter_add(ks, vs, 50, block_t=128, block_bins=32,
+                            interpret=True)
+    b = colibri_scatter_add(ks, vs, 50, block_t=512, block_bins=128,
+                            interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
 
 
@@ -60,7 +62,7 @@ def test_colibri_scatter_block_shapes():
 def test_colibri_histogram_parity(t, bins):
     """The paper's benchmark op vs the ref commit and np.bincount."""
     ks = jax.random.randint(keys(1)[0], (t,), 0, bins)
-    out = np.asarray(colibri_histogram(ks, bins))
+    out = np.asarray(colibri_histogram(ks, bins, interpret=True))
     ref = np.asarray(scatter_add_ref(
         ks, jnp.ones((t, 1), jnp.float32), bins))[:, 0].astype(np.int32)
     np.testing.assert_array_equal(out, ref)
@@ -76,10 +78,10 @@ def test_trace_latency_hist_matches_engine():
     from repro.core.sim import SimParams, execute
     res = execute(SimParams(protocol="colibri", n_cores=32, n_addrs=4,
                             cycles=4000, record_trace=True))
-    hk = metrics.trace_latency_hist(res)
+    hk = metrics.trace_latency_hist(res, interpret=True)
     np.testing.assert_array_equal(hk, np.asarray(res["lat_hist"]))
     np.testing.assert_array_equal(
-        hk, metrics.trace_latency_hist(res, use_kernel=False))
+        hk, metrics.trace_latency_hist(res))
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +102,8 @@ def test_flash_attention_sweep(b, sq, skv, h, kv, hd, causal, dtype):
     q = jax.random.normal(k1, (b, sq, h, hd), dtype)
     k = jax.random.normal(k2, (b, skv, kv, hd), dtype)
     v = jax.random.normal(k3, (b, skv, kv, hd), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     g = h // kv
     ke = jnp.repeat(k, g, axis=2).transpose(0, 2, 1, 3).reshape(b * h, skv, hd)
     ve = jnp.repeat(v, g, axis=2).transpose(0, 2, 1, 3).reshape(b * h, skv, hd)
@@ -124,7 +127,8 @@ def test_grouped_matmul_sweep(e, c, d, f, dtype):
     k1, k2 = keys(2)
     x = jax.random.normal(k1, (e, c, d), dtype)
     w = jax.random.normal(k2, (e, d, f), dtype)
-    out = grouped_matmul(x, w, block_c=64, block_f=64, block_d=64)
+    out = grouped_matmul(x, w, block_c=64, block_f=64, block_d=64,
+                         interpret=True)
     ref = grouped_matmul_ref(x, w)
     tol = 1e-4 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -145,7 +149,7 @@ def test_wkv_chunked_sweep(bh, t, hd):
     # realistic rwkv6 decay: w = exp(-exp(x)), x ~ N(-1.5, 1)
     w = jnp.exp(-jnp.exp(jax.random.normal(k4, (bh, t, hd)) - 1.5))
     u = jax.random.normal(k5, (bh, hd)) * 0.1
-    out = wkv_chunked(r, k, v, w, u, block_c=32)
+    out = wkv_chunked(r, k, v, w, u, block_c=32, interpret=True)
     ref = wkv_ref(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
@@ -159,8 +163,8 @@ def test_wkv_chunk_size_invariance():
     v = jax.random.normal(k3, (bh, t, hd))
     w = jnp.exp(-jnp.exp(jax.random.normal(k4, (bh, t, hd)) - 1.5))
     u = jax.random.normal(k5, (bh, hd)) * 0.1
-    a = wkv_chunked(r, k, v, w, u, block_c=16)
-    b = wkv_chunked(r, k, v, w, u, block_c=48)
+    a = wkv_chunked(r, k, v, w, u, block_c=16, interpret=True)
+    b = wkv_chunked(r, k, v, w, u, block_c=48, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
                                atol=2e-3)
 
@@ -175,7 +179,8 @@ def test_rglru_scan_sweep(t, b, w):
     a = jax.nn.sigmoid(jax.random.normal(k1, (t, b, w)) + 2.0)  # decay ~ (0,1)
     x = jax.random.normal(k2, (t, b, w)) * 0.3
     h0 = jax.random.normal(k3, (b, w))
-    out = rglru_scan(a, x, h0, block_c=32, block_b=2, block_w=64)
+    out = rglru_scan(a, x, h0, block_c=32, block_b=2, block_w=64,
+                     interpret=True)
     ref = rglru_scan_ref(a, x, h0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
@@ -196,7 +201,7 @@ def test_rglru_matches_model_block():
                              state["conv"])
     a, b = RG._gates(p, y.astype(jnp.float32))
     h = rglru_scan(a.transpose(1, 0, 2), b.transpose(1, 0, 2), state["h"],
-                   block_c=16).transpose(1, 0, 2)
+                   block_c=16, interpret=True).transpose(1, 0, 2)
     gate = jax.nn.gelu(x @ p["w_gate"], approximate=True)
     out_kernel = (h.astype(x.dtype) * gate) @ p["w_proj"]
     np.testing.assert_allclose(np.asarray(out_kernel), np.asarray(out_model),

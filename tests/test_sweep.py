@@ -169,3 +169,36 @@ def test_static_fields_cover_simparams():
     from repro.core.sim import DYN_FIELDS
     fields = {f.name for f in dataclasses.fields(SimParams)}
     assert fields == set(STATIC_FIELDS) | set(DYN_FIELDS)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_persistent_cache_placement(tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` places the compile cache from
+    outside, and compiles land there; unset, the cache sits at the fixed
+    ``<checkout>/.jax_cache``.  A fresh process, so the cache setting
+    never leaks into this test session."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.sync import enable_persistent_cache\n"
+        "path = enable_persistent_cache()\n"
+        "assert jax.config.jax_compilation_cache_dir == path\n"
+        + ("jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()\n"
+           if from_env else "")
+        + "print(path)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    path = out.stdout.strip().splitlines()[-1]
+    if from_env:
+        assert path == str(tmp_path)
+        assert any(tmp_path.iterdir())           # the compile landed here
+    else:
+        assert path == os.path.join(repo, ".jax_cache")

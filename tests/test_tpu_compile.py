@@ -1,0 +1,125 @@
+"""Ahead-of-time compile guard: the engine's ``pallas_tpu`` path must
+lower for a TPU v5e.
+
+The TPU compiler is installed on CPU-only hosts too, and compiles for a
+chip that is described rather than attached.  These tests compile the
+engine (``sim.simulate``) and the vmapped sweep runner with the fused
+``engine_step`` kernel for every registered protocol, at the paper's
+256-core platform and at the shapes that once broke lowering: a
+multi-tile bank grid (1024 banks), the 1024-core ``cluster2`` machine,
+4096 cores, and a sweep batch sharded over four chips.  Each compiled
+program must contain the kernel (``tpu_custom_call``).  Nothing runs, so
+these say nothing about results or speed: ``chip_smoke.py`` checks those
+on the chip.
+
+The topology is described inside a module fixture, never at import time:
+only one process may hold the TPU library, and every test worker imports
+this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core import protocols, sim, sweep
+
+CYCLES = 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_params(monkeypatch, topo, no_persistent_cache):
+    """``SimParams`` factory for ``pallas_tpu`` on this TPU-less host:
+    the backend check asks which devices are visible, so the test says
+    the TPU backends exist."""
+    monkeypatch.setattr(sim, "available_backends",
+                        lambda: tuple(sim.BACKENDS))
+
+    def make(**kw):
+        return sim.SimParams(backend="pallas_tpu", cycles=CYCLES, **kw)
+    return make
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_run(p, sharding):
+    dyn = {"seed": jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)}
+    return jax.jit(lambda d: sim.simulate(p, dyn=d)).lower(dyn).compile()
+
+
+def _sweep_dyn(batch, sharding):
+    return {f: jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=sharding)
+            for f in sim.DYN_FIELDS if f != "n_workers"}
+
+
+@pytest.mark.parametrize("protocol", protocols.names())
+def test_engine_lowers_every_protocol(protocol, tpu_params, topo):
+    from jax.sharding import SingleDeviceSharding
+    p = tpu_params(protocol=protocol, n_cores=256, n_addrs=1)
+    _assert_kernel(_compile_run(p, SingleDeviceSharding(topo.devices[0])))
+
+
+@pytest.mark.parametrize("shape", [
+    dict(protocol="lrsc", n_cores=256, n_addrs=1024),     # 4 bank tiles
+    dict(protocol="colibri_hier", n_cores=1024, n_addrs=4,
+         topology="cluster2", clusters=4),
+    dict(protocol="colibri", n_cores=4096, n_addrs=4),    # 4 core chunks
+], ids=["multi_tile", "cluster2_1024", "colibri_4096"])
+def test_engine_lowers_at_scale(shape, tpu_params, topo):
+    from jax.sharding import SingleDeviceSharding
+    _assert_kernel(_compile_run(tpu_params(**shape),
+                                SingleDeviceSharding(topo.devices[0])))
+
+
+def test_sweep_lowers_vmapped(tpu_params, topo):
+    """The Study path: the kernel under ``vmap`` over a sweep batch."""
+    from jax.sharding import SingleDeviceSharding
+    p = tpu_params(protocol="lrscwait", workload="ms_queue", n_cores=256,
+                   n_addrs=2)
+    c = sweep._sweep_group.lower(
+        p, _sweep_dyn(2, SingleDeviceSharding(topo.devices[0])), 2).compile()
+    _assert_kernel(c)
+
+
+def test_sweep_lowers_sharded_over_four_chips(tpu_params, topo):
+    """A sweep batch sharded over 4 chips compiles with no gather of the
+    batch: each chip runs the kernel on its own slice (the compiler
+    cannot partition a Mosaic kernel itself)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    mesh = Mesh(np.asarray(topo.devices), ("batch",))
+    p = dataclasses.replace(
+        tpu_params(protocol="colibri", n_cores=256, n_addrs=16),
+        n_workers=0)
+    c = sweep._sweep_group.lower(
+        p, _sweep_dyn(8, NamedSharding(mesh, PartitionSpec("batch"))), 8,
+        mesh).compile()
+    _assert_kernel(c)
+    assert "all-gather" not in c.as_text()
+    out = jax.tree.leaves(c.output_shardings)[0]
+    assert len(out.device_set) == 4
