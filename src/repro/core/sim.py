@@ -64,6 +64,7 @@ from repro.core.workloads.base import (ADDR_FIXED, ADDR_ZIPF, K_BARRIER,
                                        zipf_index)
 from repro.faults import DROP_DENOM, FaultPlan
 from repro.kernels import engine_step
+from repro.obs.runreport import span
 from repro.obs.schema import TELE_K, TELE_NSUM, window_len
 
 #: the paper's seven protocols (Figs. 3–6); the registry may hold more.
@@ -535,11 +536,17 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                             zipf_index(h, rp.n_addrs, rp.zipf_skew), out)
         return out
 
+    # Each stage of a simulated cycle runs under a ``jax.named_scope``
+    # (sim.issue, sim.retire, sim.network, sim.arbitrate, sim.wake,
+    # sim.account, sim.telemetry, sim.faults).  A scope is op-name
+    # metadata in the compiled program, so a profiler trace attributes
+    # device time to stages; it changes neither the jaxpr nor a result.
     def step(s, cyc):
         st, tmr, pc = s["st"], s["tmr"], s["pc"]
         # ---- timers ----
-        tmr = jnp.maximum(tmr - 1, 0)
-        t0 = tmr == 0
+        with jax.named_scope("sim.issue"):
+            tmr = jnp.maximum(tmr - 1, 0)
+            t0 = tmr == 0
 
         # ---- fault injection: dead/stalled cores freeze ----
         # dead = permanently killed ∪ inside a transient stall window.
@@ -547,295 +554,305 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
         # retransmits) but requests already in flight still get served —
         # if one was granted a reservation, the bank wedges: exactly the
         # failure the reservation watchdog exists for.
-        if any_core_fault:
-            if holder_mode:
-                killed = s["kmask"]
-            elif uni_kill:
-                killed = kill_m & (cyc >= fp.kill_cyc)
-            else:
-                killed = jnp.zeros((n,), bool)
-            dead = killed
-            if has_stall:
-                dead = dead | (stall_m & (cyc >= fp.stall_cyc)
-                               & (cyc < fp.stall_cyc + fp.stall_dur))
-            t0 = t0 & ~dead
-        if use_faults:
-            finj = s["faults_injected"]
-            if uni_kill:
-                finj = finj + jnp.where(cyc == fp.kill_cyc, n_kill_eff, 0)
-            if has_stall:
-                finj = finj + jnp.where(cyc == fp.stall_cyc,
-                                        n_stall_eff, 0)
-            if has_bstall:
-                finj = finj + jnp.where(cyc == fp.bank_stall_cyc,
-                                        n_bstall_eff, 0)
+        with jax.named_scope("sim.faults"):
+            if any_core_fault:
+                if holder_mode:
+                    killed = s["kmask"]
+                elif uni_kill:
+                    killed = kill_m & (cyc >= fp.kill_cyc)
+                else:
+                    killed = jnp.zeros((n,), bool)
+                dead = killed
+                if has_stall:
+                    dead = dead | (stall_m & (cyc >= fp.stall_cyc)
+                                   & (cyc < fp.stall_cyc + fp.stall_dur))
+                t0 = t0 & ~dead
+            if use_faults:
+                finj = s["faults_injected"]
+                if uni_kill:
+                    finj = finj + jnp.where(cyc == fp.kill_cyc, n_kill_eff, 0)
+                if has_stall:
+                    finj = finj + jnp.where(cyc == fp.stall_cyc,
+                                            n_stall_eff, 0)
+                if has_bstall:
+                    finj = finj + jnp.where(cyc == fp.bank_stall_cyc,
+                                            n_bstall_eff, 0)
 
         # ---- timer-expiry dispatch (one predicated block) ----
         # WORK -> issue current micro-op's acquire; BACKOFF -> reissue
         # acquire; MOD -> issue release/SC.  The three source states are
         # mutually exclusive, so a single fused REQ/latency write covers
         # what used to be three identical where-chains.
-        start = t0 & (st == WORK) & ~is_worker
-        rb = t0 & (st == BACKOFF)
-        md = t0 & (st == MOD)
-        issue = start | rb | md
-        addr = jnp.where(start, step_addr(s["opc"], pc), s["addr"])
-        phase = jnp.where(md, P_REL,
-                          jnp.where(start | rb, P_ACQ, s["phase"]))
-        st = jnp.where(issue, REQ, st)
-        if use_topo:
-            # cross-cluster requests pay the per-level extra latency once
-            # per issue (acquire, reissue, release) — the round-trip cost
-            # of the level routers on top of the flat ``lat`` baseline.
-            # Billed HERE, before the request reaches the network/bank
-            # stages, so protocols and the Pallas kernel never see
-            # topology: backends stay bit-identical by construction.
-            tmr = jnp.where(issue, rp.lat + extra_t[iota * a + addr], tmr)
-        else:
-            tmr = jnp.where(issue, rp.lat, tmr)
+        with jax.named_scope("sim.issue"):
+            start = t0 & (st == WORK) & ~is_worker
+            rb = t0 & (st == BACKOFF)
+            md = t0 & (st == MOD)
+            issue = start | rb | md
+            addr = jnp.where(start, step_addr(s["opc"], pc), s["addr"])
+            phase = jnp.where(md, P_REL,
+                              jnp.where(start | rb, P_ACQ, s["phase"]))
+            st = jnp.where(issue, REQ, st)
+            if use_topo:
+                # cross-cluster requests pay the per-level extra latency once
+                # per issue (acquire, reissue, release) — the round-trip cost
+                # of the level routers on top of the flat ``lat`` baseline.
+                # Billed HERE, before the request reaches the network/bank
+                # stages, so protocols and the Pallas kernel never see
+                # topology: backends stay bit-identical by construction.
+                tmr = jnp.where(issue, rp.lat + extra_t[iota * a + addr], tmr)
+            else:
+                tmr = jnp.where(issue, rp.lat, tmr)
 
         # ---- RESP arrives: the current micro-op retires ----
-        ra = t0 & (st == RESP)
-        done = ra & (s["nxt"] == NXT_WORK_DONE)
-        at_bar = done & kind_is_bar[pc]
-        pc_next = (pc + 1) % L
-        wrap = done & (pc_next == 0)             # program completed one op
-        go_work = done & ~at_bar
-        st = jnp.where(go_work, WORK, st)
-        st = jnp.where(at_bar, BARWAIT, st)
-        pc = jnp.where(done, pc_next, pc)
-        # next step's local work (current step's for non-retiring cores)
-        pre_dur = pre_dur_tab[pc]
-        tmr = jnp.where(go_work, pre_dur, tmr)
-        ops = s["ops"] + wrap
-        opc = s["opc"] + done
-        bar_cnt = s["bar_cnt"] + at_bar
-        # completion-latency stamp: ``start`` (st==WORK, fresh micro-op)
-        # and ``done`` (st==RESP) are mutually exclusive within a cycle,
-        # so the stamp always predates the retirement that reads it;
-        # retries (BACKOFF reissues) and queue waits keep the original
-        # stamp and therefore count toward the op's latency.
-        acq_start = jnp.where(start, cyc, s["acq_start"])
-        if dense_banks:
-            addr_ops = s["addr_ops"] + jnp.sum(
-                (addr[None, :] == ba[:, None]) & done[None, :], axis=1)
-        else:
-            addr_ops = s["addr_ops"].at[jnp.where(done, addr, a)].add(
-                1, mode="drop")
-        to_mod = ra & (s["nxt"] == NXT_MOD)
-        mod_dur = mod_dur_tab[pc]
-        st = jnp.where(to_mod, MOD, st)
-        tmr = jnp.where(to_mod, mod_dur, tmr)
-        to_bo = ra & (s["nxt"] == NXT_BACKOFF)
-        st = jnp.where(to_bo, BACKOFF, st)
-        # lock protocols use the paper's stated FIXED backoff (Fig. 4 /
-        # Table II: "spin locks with a backoff of 128 cycles"); bare LRSC
-        # uses the calibrated exponential policy.
-        streak = jnp.where(to_bo, jnp.minimum(s["streak"] + 1, exp_cap),
-                           jnp.where(done, 0, s["streak"]))
-        bo_len = (rp.backoff << jnp.maximum(streak - 1, 0)) + (_hash(
-            iota + cyc) % 32).astype(jnp.int32)
-        tmr = jnp.where(to_bo, bo_len, tmr)
+        with jax.named_scope("sim.retire"):
+            ra = t0 & (st == RESP)
+            done = ra & (s["nxt"] == NXT_WORK_DONE)
+            at_bar = done & kind_is_bar[pc]
+            pc_next = (pc + 1) % L
+            wrap = done & (pc_next == 0)             # program completed one op
+            go_work = done & ~at_bar
+            st = jnp.where(go_work, WORK, st)
+            st = jnp.where(at_bar, BARWAIT, st)
+            pc = jnp.where(done, pc_next, pc)
+            # next step's local work (current step's for non-retiring cores)
+            pre_dur = pre_dur_tab[pc]
+            tmr = jnp.where(go_work, pre_dur, tmr)
+            ops = s["ops"] + wrap
+            opc = s["opc"] + done
+            bar_cnt = s["bar_cnt"] + at_bar
+            # completion-latency stamp: ``start`` (st==WORK, fresh micro-op)
+            # and ``done`` (st==RESP) are mutually exclusive within a cycle,
+            # so the stamp always predates the retirement that reads it;
+            # retries (BACKOFF reissues) and queue waits keep the original
+            # stamp and therefore count toward the op's latency.
+            acq_start = jnp.where(start, cyc, s["acq_start"])
+            if dense_banks:
+                addr_ops = s["addr_ops"] + jnp.sum(
+                    (addr[None, :] == ba[:, None]) & done[None, :], axis=1)
+            else:
+                addr_ops = s["addr_ops"].at[jnp.where(done, addr, a)].add(
+                    1, mode="drop")
+            to_mod = ra & (s["nxt"] == NXT_MOD)
+            mod_dur = mod_dur_tab[pc]
+            st = jnp.where(to_mod, MOD, st)
+            tmr = jnp.where(to_mod, mod_dur, tmr)
+            to_bo = ra & (s["nxt"] == NXT_BACKOFF)
+            st = jnp.where(to_bo, BACKOFF, st)
+            # lock protocols use the paper's stated FIXED backoff (Fig. 4 /
+            # Table II: "spin locks with a backoff of 128 cycles"); bare LRSC
+            # uses the calibrated exponential policy.
+            streak = jnp.where(to_bo, jnp.minimum(s["streak"] + 1, exp_cap),
+                               jnp.where(done, 0, s["streak"]))
+            bo_len = (rp.backoff << jnp.maximum(streak - 1, 0)) + (_hash(
+                iota + cyc) % 32).astype(jnp.int32)
+            tmr = jnp.where(to_bo, bo_len, tmr)
 
-        # ---- barrier: last arrival releases every waiter (broadcast) ----
-        bar_msgs = jnp.zeros((), jnp.int32)
-        if has_bar:
-            min_bar = jnp.min(jnp.where(is_worker, _BIG, bar_cnt))
-            rel_bar = (st == BARWAIT) & (bar_cnt <= min_bar)
-            st = jnp.where(rel_bar, WORK, st)
-            tmr = jnp.where(rel_bar, rp.lat + pre_dur, tmr)
-            bar_msgs = rel_bar.sum().astype(jnp.int32)  # one wake msg each
+            # ---- barrier: last arrival releases every waiter (broadcast) ----
+            bar_msgs = jnp.zeros((), jnp.int32)
+            if has_bar:
+                min_bar = jnp.min(jnp.where(is_worker, _BIG, bar_cnt))
+                rel_bar = (st == BARWAIT) & (bar_cnt <= min_bar)
+                st = jnp.where(rel_bar, WORK, st)
+                tmr = jnp.where(rel_bar, rp.lat + pre_dur, tmr)
+                bar_msgs = rel_bar.sum().astype(jnp.int32)  # one wake msg each
 
-        # ---- workers stream loads (Fig. 5) ----
-        # the w_tmr/w_served updates are statically elided when the
-        # trace has no workers: the writes are semantically dead at
-        # n_workers == 0 but XLA cannot prove it, and two extra written
-        # (n,) carries push the scan body over a compile cliff (~3×
-        # wall time at 256 cores — EXPERIMENTS.md §Metric-cost)
-        if has_workers:
-            w_tmr = jnp.maximum(s["w_tmr"] - 1, 0)
-            w_arr = is_worker & (w_tmr == 0)     # a load arrives at a bank
-            if any_core_fault:
-                w_arr = w_arr & ~dead            # dead workers go silent
-        else:
-            w_tmr = s["w_tmr"]
-            w_arr = jnp.zeros((n,), bool)
+            # ---- workers stream loads (Fig. 5) ----
+            # the w_tmr/w_served updates are statically elided when the
+            # trace has no workers: the writes are semantically dead at
+            # n_workers == 0 but XLA cannot prove it, and two extra written
+            # (n,) carries push the scan body over a compile cliff (~3×
+            # wall time at 256 cores — EXPERIMENTS.md §Metric-cost)
+            if has_workers:
+                w_tmr = jnp.maximum(s["w_tmr"] - 1, 0)
+                w_arr = is_worker & (w_tmr == 0)     # a load arrives at a bank
+                if any_core_fault:
+                    w_arr = w_arr & ~dead            # dead workers go silent
+            else:
+                w_tmr = s["w_tmr"]
+                w_arr = jnp.zeros((n,), bool)
 
         # ---- network acceptance (rotating-fair, bounded bandwidth) ----
-        # A new request consumes one network slot ONCE; accepted requests are
-        # "parked" in the bank input queue and no longer use the network.
-        fresh = (st == REQ) & (tmr == 0) & ~is_worker & ~s["parked"]
-        if any_core_fault:
-            fresh = fresh & ~dead                # dead cores stop sending
-        shift = (cyc * 97) % n
-        rot = (iota + shift) % n
-        all_req = fresh | w_arr
-        # responses issued last cycle share the same links, and parked
-        # requests at saturated banks back up through switch buffers
-        # (head-of-line blocking): both shrink the request budget.
-        if isinstance(rp.hol_block, int):
-            hol = (s["parked"].sum() // rp.hol_block) if rp.hol_block else 0
-        else:
-            hol = jnp.where(rp.hol_block > 0,
-                            s["parked"].sum() // jnp.maximum(rp.hol_block, 1),
-                            0)
-        budget = jnp.maximum(rp.net_bw - s["resp_prev"] - hol, 1)
-        accepted = accept_rotating_fair(all_req, rot, budget, shift=shift)
-        if use_topo:
-            # per-level link capacity: a request whose (core, bank) path
-            # crosses level ℓ must ALSO win one of that level's
-            # ``net_bw // bw_div`` link slots this cycle (same rotating-
-            # fair arbiter, same rotation — fairness is preserved level
-            # by level).  Rejected requesters stay fresh and retry next
-            # cycle; they count into net_stall like any denied request.
-            # Worker streams stay cluster-local (their banks are the
-            # local SPM ports), so only atomic requests contend here.
-            xmask = [lx[iota * a + addr] & ~is_worker for lx in cross_t]
-            for cm, div in zip(xmask, lvl_div):
-                acc_x = accept_rotating_fair(
-                    all_req & cm, rot, jnp.maximum(rp.net_bw // div, 1),
-                    shift=shift)
-                accepted = accepted & (~cm | acc_x)
-        # Bernoulli NoC drop on newly-accepted requests: the message
-        # dies in flight, the core stays in REQ and retransmits next
-        # cycle; the wasted link hop is billed into msgs below
-        if has_drop:
-            u = _hash(iota * 9781 + cyc * 6271 + fp.fault_seed * 977 + 13)
-            req_drop = (fresh & accepted
-                        & ((u % DROP_DENOM) < fp.msg_drop_bp))
-            accepted = accepted & ~req_drop
-            n_req_drop = req_drop.sum()
-            finj = finj + n_req_drop
-        w_acc = w_arr & accepted
-        if has_workers:
-            w_served = s["w_served"] + w_acc
-            w_tmr = jnp.where(w_acc, 2, w_tmr)   # pipelined stream of loads
-            w_tmr = jnp.where(is_worker & (w_tmr == 0), 1, w_tmr)
-        else:
-            w_served = s["w_served"]
-        stall_now = (all_req & ~accepted).sum()
-        net_stall = s["net_stall"] + stall_now
-        parked = s["parked"] | (fresh & accepted)
-        arr_cyc = jnp.where(fresh & accepted, cyc, s["arr_cyc"])
-        if use_topo:
-            # hop accounting for the energy model: every accepted
-            # request traverses its (core, bank) hop path twice (request
-            # + response); accepted worker loads are cluster-local
-            # single-hop round trips.
-            hops_cnt = (s["hops"]
-                        + 2 * jnp.where(fresh & accepted,
-                                        hops_t[iota * a + addr], 0).sum()
-                        + 2 * w_acc.sum())
+        with jax.named_scope("sim.network"):
+            # A new request consumes one network slot ONCE; accepted
+            # requests are "parked" in the bank input queue and no longer
+            # use the network.
+            fresh = (st == REQ) & (tmr == 0) & ~is_worker & ~s["parked"]
+            if any_core_fault:
+                fresh = fresh & ~dead                # dead cores stop sending
+            shift = (cyc * 97) % n
+            rot = (iota + shift) % n
+            all_req = fresh | w_arr
+            # responses issued last cycle share the same links, and parked
+            # requests at saturated banks back up through switch buffers
+            # (head-of-line blocking): both shrink the request budget.
+            if isinstance(rp.hol_block, int):
+                hol = ((s["parked"].sum() // rp.hol_block)
+                       if rp.hol_block else 0)
+            else:
+                hol = jnp.where(
+                    rp.hol_block > 0,
+                    s["parked"].sum() // jnp.maximum(rp.hol_block, 1), 0)
+            budget = jnp.maximum(rp.net_bw - s["resp_prev"] - hol, 1)
+            accepted = accept_rotating_fair(all_req, rot, budget, shift=shift)
+            if use_topo:
+                # per-level link capacity: a request whose (core, bank) path
+                # crosses level ℓ must ALSO win one of that level's
+                # ``net_bw // bw_div`` link slots this cycle (same rotating-
+                # fair arbiter, same rotation — fairness is preserved level
+                # by level).  Rejected requesters stay fresh and retry next
+                # cycle; they count into net_stall like any denied request.
+                # Worker streams stay cluster-local (their banks are the
+                # local SPM ports), so only atomic requests contend here.
+                xmask = [lx[iota * a + addr] & ~is_worker for lx in cross_t]
+                for cm, div in zip(xmask, lvl_div):
+                    acc_x = accept_rotating_fair(
+                        all_req & cm, rot, jnp.maximum(rp.net_bw // div, 1),
+                        shift=shift)
+                    accepted = accepted & (~cm | acc_x)
+            # Bernoulli NoC drop on newly-accepted requests: the message
+            # dies in flight, the core stays in REQ and retransmits next
+            # cycle; the wasted link hop is billed into msgs below
+            with jax.named_scope("sim.faults"):
+                if has_drop:
+                    u = _hash(iota * 9781 + cyc * 6271
+                              + fp.fault_seed * 977 + 13)
+                    req_drop = (fresh & accepted
+                                & ((u % DROP_DENOM) < fp.msg_drop_bp))
+                    accepted = accepted & ~req_drop
+                    n_req_drop = req_drop.sum()
+                    finj = finj + n_req_drop
+            w_acc = w_arr & accepted
+            if has_workers:
+                w_served = s["w_served"] + w_acc
+                w_tmr = jnp.where(w_acc, 2, w_tmr)   # pipelined loads
+                w_tmr = jnp.where(is_worker & (w_tmr == 0), 1, w_tmr)
+            else:
+                w_served = s["w_served"]
+            stall_now = (all_req & ~accepted).sum()
+            net_stall = s["net_stall"] + stall_now
+            parked = s["parked"] | (fresh & accepted)
+            arr_cyc = jnp.where(fresh & accepted, cyc, s["arr_cyc"])
+            if use_topo:
+                # hop accounting for the energy model: every accepted
+                # request traverses its (core, bank) hop path twice (request
+                # + response); accepted worker loads are cluster-local
+                # single-hop round trips.
+                hops_cnt = (s["hops"]
+                            + 2 * jnp.where(fresh & accepted,
+                                            hops_t[iota * a + addr], 0).sum()
+                            + 2 * w_acc.sum())
 
         # ---- bank arbitration: FIFO by arrival stamp among parked ----
-        arrived = parked & (st == REQ)
-        # bank-stall window: stalled banks accept no requests (parked
-        # requesters keep waiting); masking the arbitration INPUT makes
-        # the scan and pallas paths identical by construction (the
-        # kernel sees the masked cand_cyc)
-        if has_bstall:
-            bs_now = ((cyc >= fp.bank_stall_cyc)
-                      & (cyc < fp.bank_stall_cyc + fp.bank_stall_dur))
-            arrived = arrived & ~(bstall_m[addr] & bs_now)
-        if use_pallas:
-            # fused engine-step kernel (repro.kernels.engine_step):
-            # arbitration + protocol bank update + latency histogram in
-            # one tiled pass over (a, n); the engine scatters the
-            # per-bank outcome codes back to the winning cores below —
-            # exactly the (st, tmr, nxt) writes on_access performs via
-            # masked wheres, so the two paths stay bit-identical
-            # (tests/test_engine_backend.py).
-            fs = engine_step.fused_step(
-                proto, p, dict(s["bank"]),
-                cand_cyc=jnp.where(arrived, arr_cyc, _BIG),
-                rot=rot, addr=addr, phase=phase, acq_start=acq_start,
-                core={f: s["xc"][f] for f in proto.fused_core_fields},
-                cyc=cyc, shift=shift, lat=rp.lat,
-                n=n, a=a, q_cap=q_cap, cycles=p.cycles,
-                interpret=pl_interpret)
-            valid_b, win_core, kind = fs["valid"], fs["win"], fs["kind"]
-            winner = jnp.zeros((n,), bool).at[
-                jnp.where(valid_b, win_core, n)].set(True, mode="drop")
-            parked = parked & ~winner                    # served
-            arr_cyc = jnp.where(winner, -1, arr_cyc)
-            wcs = jnp.minimum(win_core, n - 1)           # gather-safe
-            acq_b = valid_b & (phase[wcs] == P_ACQ)
-            rel_b = valid_b & (phase[wcs] == P_REL)
-            is_acq = winner & (phase == P_ACQ)
-            is_rel = winner & (phase == P_REL)
-            resp_k = ((kind == OUT_GRANT) | (kind == OUT_DONE)
-                      | (kind == OUT_FAIL))
-            rw = jnp.where(resp_k, win_core, n)
-            st = st.at[rw].set(RESP, mode="drop")
-            st = st.at[jnp.where(kind == OUT_SLEEP, win_core, n)].set(
-                SLEEP, mode="drop")
-            tmr = tmr.at[rw].set(fs["tmr"], mode="drop")
-            nxt_code = jnp.where(
-                kind == OUT_GRANT, NXT_MOD,
-                jnp.where(kind == OUT_DONE, NXT_WORK_DONE,
-                          NXT_BACKOFF)).astype(jnp.int32)
-            nxt = s["nxt"].at[rw].set(nxt_code, mode="drop")
-            cs = dict(st=st, tmr=tmr, nxt=nxt,
-                      polls=s["polls"] + fs["polls"],
-                      msgs=(s["msgs"] + 2 * winner.sum() + bar_msgs
-                            + fs["msgs"]),
-                      **{k: s["xc"][k] for k in xc_keys})
-            # protocol per-core writes (e.g. the ticket lock's drawn
-            # ticket) come back as (values, mask) pairs
-            for f in proto.fused_xset_fields:
-                val, msk = fs["xset"][f]
-                cs[f] = cs[f].at[jnp.where(msk, win_core, n)].set(
-                    val, mode="drop")
-            bank = fs["bank"]
-            ctx = proto_registry.Ctx(p=rp, n=n, a=a, q_cap=q_cap,
-                                     is_acq=is_acq, is_rel=is_rel,
-                                     wa=addr, wc=iota, ba=ba,
-                                     win_core=win_core, acq_b=acq_b,
-                                     rel_b=rel_b,
-                                     mod_dur=mod_dur)
-        else:
-            if key_fits_int32:
-                # fused lexicographic key, one segment-min (the common
-                # case: the horizon is known at trace time to keep it
-                # in int32)
-                bkey = jnp.where(arrived, arr_cyc * (n + 1) + rot, _BIG)
-                if dense_banks:        # few banks: vectorized 2-D min
-                    best = jnp.min(jnp.where(addr[None, :] == ba[:, None],
-                                             bkey[None, :], _BIG), axis=1)
-                else:                  # many banks: one segment-min
-                    best = jnp.full((a,), _BIG, jnp.int32).at[addr].min(
-                        bkey)
-                winner = arrived & (bkey == best[addr])
-                valid_b = best != _BIG
-                rot_w = best % (n + 1)   # key encodes the winner's rot
+        with jax.named_scope("sim.arbitrate"):
+            arrived = parked & (st == REQ)
+            # bank-stall window: stalled banks accept no requests (parked
+            # requesters keep waiting); masking the arbitration INPUT makes
+            # the scan and pallas paths identical by construction (the
+            # kernel sees the masked cand_cyc)
+            with jax.named_scope("sim.faults"):
+                if has_bstall:
+                    bs_now = ((cyc >= fp.bank_stall_cyc)
+                              & (cyc < fp.bank_stall_cyc + fp.bank_stall_dur))
+                    arrived = arrived & ~(bstall_m[addr] & bs_now)
+            if use_pallas:
+                # fused engine-step kernel (repro.kernels.engine_step):
+                # arbitration + protocol bank update + latency histogram in
+                # one tiled pass over (a, n); the engine scatters the
+                # per-bank outcome codes back to the winning cores below —
+                # exactly the (st, tmr, nxt) writes on_access performs via
+                # masked wheres, so the two paths stay bit-identical
+                # (tests/test_engine_backend.py).
+                fs = engine_step.fused_step(
+                    proto, p, dict(s["bank"]),
+                    cand_cyc=jnp.where(arrived, arr_cyc, _BIG),
+                    rot=rot, addr=addr, phase=phase, acq_start=acq_start,
+                    core={f: s["xc"][f] for f in proto.fused_core_fields},
+                    cyc=cyc, shift=shift, lat=rp.lat,
+                    n=n, a=a, q_cap=q_cap, cycles=p.cycles,
+                    interpret=pl_interpret)
+                valid_b, win_core, kind = fs["valid"], fs["win"], fs["kind"]
+                winner = jnp.zeros((n,), bool).at[
+                    jnp.where(valid_b, win_core, n)].set(True, mode="drop")
+                parked = parked & ~winner                    # served
+                arr_cyc = jnp.where(winner, -1, arr_cyc)
+                wcs = jnp.minimum(win_core, n - 1)           # gather-safe
+                acq_b = valid_b & (phase[wcs] == P_ACQ)
+                rel_b = valid_b & (phase[wcs] == P_REL)
+                is_acq = winner & (phase == P_ACQ)
+                is_rel = winner & (phase == P_REL)
+                resp_k = ((kind == OUT_GRANT) | (kind == OUT_DONE)
+                          | (kind == OUT_FAIL))
+                rw = jnp.where(resp_k, win_core, n)
+                st = st.at[rw].set(RESP, mode="drop")
+                st = st.at[jnp.where(kind == OUT_SLEEP, win_core, n)].set(
+                    SLEEP, mode="drop")
+                tmr = tmr.at[rw].set(fs["tmr"], mode="drop")
+                nxt_code = jnp.where(
+                    kind == OUT_GRANT, NXT_MOD,
+                    jnp.where(kind == OUT_DONE, NXT_WORK_DONE,
+                              NXT_BACKOFF)).astype(jnp.int32)
+                nxt = s["nxt"].at[rw].set(nxt_code, mode="drop")
+                cs = dict(st=st, tmr=tmr, nxt=nxt,
+                          polls=s["polls"] + fs["polls"],
+                          msgs=(s["msgs"] + 2 * winner.sum() + bar_msgs
+                                + fs["msgs"]),
+                          **{k: s["xc"][k] for k in xc_keys})
+                # protocol per-core writes (e.g. the ticket lock's drawn
+                # ticket) come back as (values, mask) pairs
+                for f in proto.fused_xset_fields:
+                    val, msk = fs["xset"][f]
+                    cs[f] = cs[f].at[jnp.where(msk, win_core, n)].set(
+                        val, mode="drop")
+                bank = fs["bank"]
+                ctx = proto_registry.Ctx(p=rp, n=n, a=a, q_cap=q_cap,
+                                         is_acq=is_acq, is_rel=is_rel,
+                                         wa=addr, wc=iota, ba=ba,
+                                         win_core=win_core, acq_b=acq_b,
+                                         rel_b=rel_b,
+                                         mod_dur=mod_dur)
             else:
-                # long horizons: chained segment-mins, no overflow
-                winner, rot_w, valid_b = _fifo_lex_best(arrived, arr_cyc,
-                                                        rot, addr, a)
-            parked = parked & ~winner                    # served
-            arr_cyc = jnp.where(winner, -1, arr_cyc)
-            # decode each bank's winning CORE from its winning rot (the
-            # rotation is affine) — protocols use it to update bank state
-            # densely, O(a) instead of an n-lane scatter per array
-            win_core = jnp.where(valid_b, (rot_w - shift) % n, n)
-            wcs = jnp.minimum(win_core, n - 1)           # gather-safe
+                if key_fits_int32:
+                    # fused lexicographic key, one segment-min (the common
+                    # case: the horizon is known at trace time to keep it
+                    # in int32)
+                    bkey = jnp.where(arrived, arr_cyc * (n + 1) + rot, _BIG)
+                    if dense_banks:        # few banks: vectorized 2-D min
+                        best = jnp.min(jnp.where(addr[None, :] == ba[:, None],
+                                                 bkey[None, :], _BIG), axis=1)
+                    else:                  # many banks: one segment-min
+                        best = jnp.full((a,), _BIG, jnp.int32).at[addr].min(
+                            bkey)
+                    winner = arrived & (bkey == best[addr])
+                    valid_b = best != _BIG
+                    rot_w = best % (n + 1)   # key encodes the winner's rot
+                else:
+                    # long horizons: chained segment-mins, no overflow
+                    winner, rot_w, valid_b = _fifo_lex_best(arrived, arr_cyc,
+                                                            rot, addr, a)
+                parked = parked & ~winner                    # served
+                arr_cyc = jnp.where(winner, -1, arr_cyc)
+                # decode each bank's winning CORE from its winning rot (the
+                # rotation is affine) — protocols use it to update bank state
+                # densely, O(a) instead of an n-lane scatter per array
+                win_core = jnp.where(valid_b, (rot_w - shift) % n, n)
+                wcs = jnp.minimum(win_core, n - 1)           # gather-safe
 
-            # ---- protocol plugin handles the bank winners ----
-            is_acq = winner & (phase == P_ACQ)
-            is_rel = winner & (phase == P_REL)
-            acq_b = valid_b & (phase[wcs] == P_ACQ)
-            rel_b = valid_b & (phase[wcs] == P_REL)
-            cs = dict(st=st, tmr=tmr, nxt=s["nxt"], polls=s["polls"],
-                      msgs=s["msgs"] + 2 * winner.sum() + bar_msgs,
-                      **{k: s["xc"][k] for k in xc_keys})
-            ctx = proto_registry.Ctx(p=rp, n=n, a=a, q_cap=q_cap,
-                                     is_acq=is_acq, is_rel=is_rel,
-                                     wa=addr, wc=iota, ba=ba,
-                                     win_core=win_core, acq_b=acq_b,
-                                     rel_b=rel_b,
-                                     mod_dur=mod_dur)
-            cs, bank = proto.on_access(ctx, cs, dict(s["bank"]))
-        bank_ops = s["bank_ops"] + winner.sum()
+                # ---- protocol plugin handles the bank winners ----
+                is_acq = winner & (phase == P_ACQ)
+                is_rel = winner & (phase == P_REL)
+                acq_b = valid_b & (phase[wcs] == P_ACQ)
+                rel_b = valid_b & (phase[wcs] == P_REL)
+                cs = dict(st=st, tmr=tmr, nxt=s["nxt"], polls=s["polls"],
+                          msgs=s["msgs"] + 2 * winner.sum() + bar_msgs,
+                          **{k: s["xc"][k] for k in xc_keys})
+                ctx = proto_registry.Ctx(p=rp, n=n, a=a, q_cap=q_cap,
+                                         is_acq=is_acq, is_rel=is_rel,
+                                         wa=addr, wc=iota, ba=ba,
+                                         win_core=win_core, acq_b=acq_b,
+                                         rel_b=rel_b,
+                                         mod_dur=mod_dur)
+                cs, bank = proto.on_access(ctx, cs, dict(s["bank"]))
+            bank_ops = s["bank_ops"] + winner.sum()
 
         # ---- telemetry: bank-access outcome tallies (pre-wake) ----
         # Derived generically instead of per-protocol: on the pallas
@@ -846,17 +863,18 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
         # (see core.protocols.base), so both backends tally identically.
         # O(a) gathers; captured BEFORE on_wake so wake-ups never
         # shadow this cycle's outcomes.
-        if use_tele:
-            if use_pallas:
-                oc = engine_step.outcome_counts(fs["kind"])
-            else:
-                st_b, nxt_b = cs["st"][wcs], cs["nxt"][wcs]
-                resp_b = valid_b & (st_b == RESP)
-                oc = dict(
-                    grants=(resp_b & (nxt_b == NXT_MOD)).sum(),
-                    retires=(resp_b & (nxt_b == NXT_WORK_DONE)).sum(),
-                    fails=(resp_b & (nxt_b == NXT_BACKOFF)).sum(),
-                    enqueues=(valid_b & (st_b == SLEEP)).sum())
+        with jax.named_scope("sim.telemetry"):
+            if use_tele:
+                if use_pallas:
+                    oc = engine_step.outcome_counts(fs["kind"])
+                else:
+                    st_b, nxt_b = cs["st"][wcs], cs["nxt"][wcs]
+                    resp_b = valid_b & (st_b == RESP)
+                    oc = dict(
+                        grants=(resp_b & (nxt_b == NXT_MOD)).sum(),
+                        retires=(resp_b & (nxt_b == NXT_WORK_DONE)).sum(),
+                        fails=(resp_b & (nxt_b == NXT_BACKOFF)).sum(),
+                        enqueues=(valid_b & (st_b == SLEEP)).sum())
         if use_tele or use_wd or holder_mode:
             st_pre_wake = cs["st"]
 
@@ -865,83 +883,87 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
         # msg_drop_bp probability — the sleeping head never hears it.
         # Without a watchdog the bank wedges forever; this is the
         # classic lost-wakeup hazard recovery must cover.
-        wake_load = jnp.zeros((), jnp.int32)
-        if proto.uses_queue and has_drop:
-            wt = bank["wake_tmr"]
-            uw = _hash(ba * 3643 + cyc * 9176 + fp.fault_seed * 389 + 7)
-            wdrop = (wt == 1) & ((uw % DROP_DENOM) < fp.msg_drop_bp)
-            bank["wake_tmr"] = jnp.where(wdrop, 0, wt)
-            finj = finj + wdrop.sum()
-        if proto.uses_queue:
-            cs, bank, wake_load = proto.on_wake(ctx, cs, bank)
+        with jax.named_scope("sim.wake"):
+            wake_load = jnp.zeros((), jnp.int32)
+            with jax.named_scope("sim.faults"):
+                if proto.uses_queue and has_drop:
+                    wt = bank["wake_tmr"]
+                    uw = _hash(ba * 3643 + cyc * 9176
+                               + fp.fault_seed * 389 + 7)
+                    wdrop = (wt == 1) & ((uw % DROP_DENOM) < fp.msg_drop_bp)
+                    bank["wake_tmr"] = jnp.where(wdrop, 0, wt)
+                    finj = finj + wdrop.sum()
+            if proto.uses_queue:
+                cs, bank, wake_load = proto.on_wake(ctx, cs, bank)
 
         # ---- fault recovery: holder kills + reservation watchdog ----
-        if holder_mode or use_wd:
-            # per-bank grant/retire flags.  Pallas: straight from the
-            # kernel's outcome codes; scan: recovered from the (st, nxt)
-            # the protocol wrote at each winner.  Reading AFTER on_wake
-            # is still exact — a winner was REQ this cycle, never
-            # sleeping, so on_wake cannot have touched it.
-            if use_pallas:
-                grant_bk = fs["kind"] == OUT_GRANT
-                retire_bk = fs["kind"] == OUT_DONE
-            else:
-                stb, nxb = cs["st"][wcs], cs["nxt"][wcs]
-                grant_bk = valid_b & (stb == RESP) & (nxb == NXT_MOD)
-                retire_bk = valid_b & (stb == RESP) & (nxb
-                                                       == NXT_WORK_DONE)
-            # queue protocols hand ownership over by WAKE after warmup
-            # (a bank-side OUT_GRANT needs an empty queue) — a woken
-            # core is the new owner just as much as a granted one
-            woken = (((st_pre_wake == SLEEP) & (cs["st"] != SLEEP))
-                     if proto.uses_queue else jnp.zeros((n,), bool))
-        if holder_mode:
-            # targeted holder kill: the first n_kill cores handed
-            # ownership (bank grant or wake) at or after kill_cyc die
-            # while holding — the adversarial case (reservation/lock
-            # owner vanishes mid-critical-section)
-            gcore = jnp.zeros((n,), bool).at[
-                jnp.where(grant_bk, win_core, n)].set(True, mode="drop")
-            cand = (gcore | woken) & (cyc >= fp.kill_cyc) & ~s["kmask"]
-            rank = jnp.cumsum(cand.astype(jnp.int32)) - 1
-            newk = cand & (rank < s["kleft"])
-            kmask = s["kmask"] | newk
-            kleft = s["kleft"] - newk.sum()
-            finj = finj + newk.sum()
-            killed = kmask                       # includes this cycle's
-        if use_wd:
-            # reservation watchdog: per-bank service timer, re-armed on
-            # every sign of life (not held / a retire / a wake handoff).
-            # Grants do NOT re-arm it — under lrsc a dead holder lets
-            # doomed LRs keep "granting" forever, which is exactly the
-            # livelock the watchdog must see through.
-            held_b = proto.held(bank)
-            wd_own = jnp.where(grant_bk, win_core, s["wd_own"])
-            wd_own = wd_own.at[jnp.where(woken, addr, a)].set(
-                iota, mode="drop")
-            wd_srv = jnp.where(~held_b | retire_bk, cyc, s["wd_srv"])
-            wd_srv = wd_srv.at[jnp.where(woken, addr, a)].set(
-                cyc, mode="drop")
-            stuck_b = held_b & (cyc - wd_srv >= fp.watchdog_cyc)
-            killed_perm = (killed if (holder_mode or uni_kill)
-                           else jnp.zeros((n,), bool))
-            cs, bank, rkind = proto.on_timeout(ctx, cs, bank, stuck_b,
-                                               killed_perm, wd_own)
-            recoveries = s["recoveries"] + (rkind != OUT_NONE).sum()
-            wd_srv = jnp.where(stuck_b, cyc, wd_srv)     # re-arm
-            # an eviction vacates the bank: forget the owner, else a
-            # second timeout blames the dead core again and (e.g. for
-            # ticket_lock) skips a LIVE waiter's turn — the next grant
-            # or wake re-learns it
-            wd_own = jnp.where(rkind == OUT_EVICT, n, wd_own)
-        if use_faults:
-            # forward-progress watchdog: no retirement anywhere for
-            # prog_thr cycles => flag the halt cycle (detected livelock/
-            # deadlock — the run completes and reports, never hangs)
-            last_ret = jnp.where(done.any(), cyc, s["last_ret"])
-            halt_cyc = jnp.where(
-                (s["halt_cyc"] < 0) & (cyc - last_ret >= prog_thr),
-                cyc, s["halt_cyc"])
+        with jax.named_scope("sim.faults"):
+            if holder_mode or use_wd:
+                # per-bank grant/retire flags.  Pallas: straight from the
+                # kernel's outcome codes; scan: recovered from the (st, nxt)
+                # the protocol wrote at each winner.  Reading AFTER on_wake
+                # is still exact — a winner was REQ this cycle, never
+                # sleeping, so on_wake cannot have touched it.
+                if use_pallas:
+                    grant_bk = fs["kind"] == OUT_GRANT
+                    retire_bk = fs["kind"] == OUT_DONE
+                else:
+                    stb, nxb = cs["st"][wcs], cs["nxt"][wcs]
+                    grant_bk = valid_b & (stb == RESP) & (nxb == NXT_MOD)
+                    retire_bk = valid_b & (stb == RESP) & (nxb
+                                                           == NXT_WORK_DONE)
+                # queue protocols hand ownership over by WAKE after warmup
+                # (a bank-side OUT_GRANT needs an empty queue) — a woken
+                # core is the new owner just as much as a granted one
+                woken = (((st_pre_wake == SLEEP) & (cs["st"] != SLEEP))
+                         if proto.uses_queue else jnp.zeros((n,), bool))
+            if holder_mode:
+                # targeted holder kill: the first n_kill cores handed
+                # ownership (bank grant or wake) at or after kill_cyc die
+                # while holding — the adversarial case (reservation/lock
+                # owner vanishes mid-critical-section)
+                gcore = jnp.zeros((n,), bool).at[
+                    jnp.where(grant_bk, win_core, n)].set(True, mode="drop")
+                cand = (gcore | woken) & (cyc >= fp.kill_cyc) & ~s["kmask"]
+                rank = jnp.cumsum(cand.astype(jnp.int32)) - 1
+                newk = cand & (rank < s["kleft"])
+                kmask = s["kmask"] | newk
+                kleft = s["kleft"] - newk.sum()
+                finj = finj + newk.sum()
+                killed = kmask                       # includes this cycle's
+            if use_wd:
+                # reservation watchdog: per-bank service timer, re-armed on
+                # every sign of life (not held / a retire / a wake handoff).
+                # Grants do NOT re-arm it — under lrsc a dead holder lets
+                # doomed LRs keep "granting" forever, which is exactly the
+                # livelock the watchdog must see through.
+                held_b = proto.held(bank)
+                wd_own = jnp.where(grant_bk, win_core, s["wd_own"])
+                wd_own = wd_own.at[jnp.where(woken, addr, a)].set(
+                    iota, mode="drop")
+                wd_srv = jnp.where(~held_b | retire_bk, cyc, s["wd_srv"])
+                wd_srv = wd_srv.at[jnp.where(woken, addr, a)].set(
+                    cyc, mode="drop")
+                stuck_b = held_b & (cyc - wd_srv >= fp.watchdog_cyc)
+                killed_perm = (killed if (holder_mode or uni_kill)
+                               else jnp.zeros((n,), bool))
+                cs, bank, rkind = proto.on_timeout(ctx, cs, bank, stuck_b,
+                                                   killed_perm, wd_own)
+                recoveries = s["recoveries"] + (rkind != OUT_NONE).sum()
+                wd_srv = jnp.where(stuck_b, cyc, wd_srv)     # re-arm
+                # an eviction vacates the bank: forget the owner, else a
+                # second timeout blames the dead core again and (e.g. for
+                # ticket_lock) skips a LIVE waiter's turn — the next grant
+                # or wake re-learns it
+                wd_own = jnp.where(rkind == OUT_EVICT, n, wd_own)
+            if use_faults:
+                # forward-progress watchdog: no retirement anywhere for
+                # prog_thr cycles => flag the halt cycle (detected livelock/
+                # deadlock — the run completes and reports, never hangs)
+                last_ret = jnp.where(done.any(), cyc, s["last_ret"])
+                halt_cyc = jnp.where(
+                    (s["halt_cyc"] < 0) & (cyc - last_ret >= prog_thr),
+                    cyc, s["halt_cyc"])
 
         # network slots consumed by this cycle's responses and protocol
         # side-messages (SuccessorUpdate / WakeUpRequest / Mwait setup)
@@ -961,52 +983,57 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
         # the pallas backends the kernel already accumulated this
         # cycle's rows (OUT_DONE grants are exactly the RESP/WORK_DONE
         # winners, and on_wake never touches them).
-        if use_pallas:
-            lat_hist = s["lat_hist"] + fs["hist"]
-            lat_max = jnp.maximum(s["lat_max"], fs["lat_max"])
-        else:
-            fut = valid_b & (st[wcs] == RESP) & (cs["nxt"][wcs]
-                                                 == NXT_WORK_DONE)
-            done_cyc = cyc + jnp.maximum(tmr[wcs], 1)
-            fut = fut & (done_cyc < p.cycles)
-            lat_b = done_cyc - acq_start[wcs]
-            lbkt = jnp.clip((LAT_SUB * jnp.log2(
-                lat_b.astype(jnp.float32) + 1.0)).astype(jnp.int32),
-                0, LAT_BINS - 1)
-            if dense_lat:
-                lat_hist = s["lat_hist"] + jnp.sum(
-                    (lbkt[None, :] == lbins[:, None]) & fut[None, :],
-                    axis=1)
+        with jax.named_scope("sim.account"):
+            if use_pallas:
+                lat_hist = s["lat_hist"] + fs["hist"]
+                lat_max = jnp.maximum(s["lat_max"], fs["lat_max"])
             else:
-                lat_hist = s["lat_hist"].at[
-                    jnp.where(fut, lbkt, LAT_BINS)].add(1, mode="drop")
-            lat_max = jnp.maximum(s["lat_max"],
-                                  jnp.max(jnp.where(fut, lat_b, 0)))
-        extra = cs["msgs"] - s["msgs"] - 2 * winner.sum()
-        resp_load = winner.sum() + w_acc.sum() + extra + wake_load
-        if has_drop:
-            # the dropped request traversed the NoC once before dying;
-            # billed after ``extra`` so it never occupies a response slot
-            cs["msgs"] = cs["msgs"] + n_req_drop
+                fut = valid_b & (st[wcs] == RESP) & (cs["nxt"][wcs]
+                                                     == NXT_WORK_DONE)
+                done_cyc = cyc + jnp.maximum(tmr[wcs], 1)
+                fut = fut & (done_cyc < p.cycles)
+                lat_b = done_cyc - acq_start[wcs]
+                lbkt = jnp.clip((LAT_SUB * jnp.log2(
+                    lat_b.astype(jnp.float32) + 1.0)).astype(jnp.int32),
+                    0, LAT_BINS - 1)
+                if dense_lat:
+                    lat_hist = s["lat_hist"] + jnp.sum(
+                        (lbkt[None, :] == lbins[:, None]) & fut[None, :],
+                        axis=1)
+                else:
+                    lat_hist = s["lat_hist"].at[
+                        jnp.where(fut, lbkt, LAT_BINS)].add(1, mode="drop")
+                lat_max = jnp.maximum(s["lat_max"],
+                                      jnp.max(jnp.where(fut, lat_b, 0)))
+        with jax.named_scope("sim.wake"):
+            extra = cs["msgs"] - s["msgs"] - 2 * winner.sum()
+            resp_load = winner.sum() + w_acc.sum() + extra + wake_load
+            with jax.named_scope("sim.faults"):
+                if has_drop:
+                    # the dropped request traversed the NoC once before dying;
+                    # billed after ``extra`` so it never occupies a
+                    # response slot
+                    cs["msgs"] = cs["msgs"] + n_req_drop
         # per-cycle state census, shared by the cumulative stats and the
         # telemetry row (hoisted so telemetry adds no second n-lane pass)
-        sleep_now = (st == SLEEP).sum()
-        bar_now = (st == BARWAIT).sum()
-        backoff_now = (st == BACKOFF).sum()
-        active_now = ((st != SLEEP) & (st != BARWAIT) & ~is_worker).sum()
-        sleep_cyc = s["sleep_cyc"] + sleep_now
-        bar_cyc = s["bar_cyc"] + bar_now
-        backoff_cyc = s["backoff_cyc"] + backoff_now
-        active_cyc = s["active_cyc"] + active_now
+        with jax.named_scope("sim.account"):
+            sleep_now = (st == SLEEP).sum()
+            bar_now = (st == BARWAIT).sum()
+            backoff_now = (st == BACKOFF).sum()
+            active_now = ((st != SLEEP) & (st != BARWAIT) & ~is_worker).sum()
+            sleep_cyc = s["sleep_cyc"] + sleep_now
+            bar_cyc = s["bar_cyc"] + bar_now
+            backoff_cyc = s["backoff_cyc"] + backoff_now
+            active_cyc = s["active_cyc"] + active_now
 
-        # ---- end-of-cycle queue depths (telemetry + event trace) ----
-        # per-bank reservation-queue occupancy via the protocol's
-        # queue_depth view (None for queueless protocols -> zeros); read
-        # AFTER on_wake so popped heads are reflected
-        if use_tele or p.record_trace:
-            qd = proto.queue_depth(bank)
-            qd = (jnp.zeros((a,), jnp.int32) if qd is None
-                  else qd.astype(jnp.int32))
+            # ---- end-of-cycle queue depths (telemetry + event trace) ----
+            # per-bank reservation-queue occupancy via the protocol's
+            # queue_depth view (None for queueless protocols -> zeros); read
+            # AFTER on_wake so popped heads are reflected
+            if use_tele or p.record_trace:
+                qd = proto.queue_depth(bank)
+                qd = (jnp.zeros((a,), jnp.int32) if qd is None
+                      else qd.astype(jnp.int32))
         out = dict(st=st, tmr=tmr, addr=addr, phase=phase, nxt=cs["nxt"],
                    pc=pc, bar_cnt=bar_cnt,
                    opc=opc, arr_cyc=arr_cyc, streak=streak, parked=parked,
@@ -1036,35 +1063,37 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
         # division; no cyc * n_windows product).  Column order follows
         # obs.schema.TELE_CHANNELS; the final queue_max column is
         # max-accumulated, everything else summed.
-        if use_tele:
-            wakes = (((st_pre_wake == SLEEP) & (st != SLEEP)).sum()
-                     if proto.uses_queue else jnp.zeros((), jnp.int32))
-            # NoC link locality: accepted requests split by whether the
-            # (core, bank) path crosses the leaf-cluster boundary.  On
-            # the flat topology the split is the Python constant
-            # "everything local" — no extra work traced.
-            if use_topo:
-                xcl_now = (accepted & xmask[0]).sum().astype(jnp.int32)
-            else:
-                xcl_now = jnp.zeros((), jnp.int32)
-            loc_now = accepted.sum().astype(jnp.int32) - xcl_now
-            row = jnp.stack([active_now, sleep_now, backoff_now, bar_now,
-                             oc["grants"], oc["retires"], oc["fails"],
-                             oc["enqueues"], wakes, cs["msgs"] - s["msgs"],
-                             stall_now, loc_now, xcl_now,
-                             qd.sum()]).astype(jnp.int32)
-            w = cyc // tele_cw
-            tele = s["tele"].at[w, :TELE_NSUM].add(row)
-            out["tele"] = tele.at[w, TELE_NSUM].max(qd.max())
+        with jax.named_scope("sim.telemetry"):
+            if use_tele:
+                wakes = (((st_pre_wake == SLEEP) & (st != SLEEP)).sum()
+                         if proto.uses_queue else jnp.zeros((), jnp.int32))
+                # NoC link locality: accepted requests split by whether the
+                # (core, bank) path crosses the leaf-cluster boundary.  On
+                # the flat topology the split is the Python constant
+                # "everything local" — no extra work traced.
+                if use_topo:
+                    xcl_now = (accepted & xmask[0]).sum().astype(jnp.int32)
+                else:
+                    xcl_now = jnp.zeros((), jnp.int32)
+                loc_now = accepted.sum().astype(jnp.int32) - xcl_now
+                row = jnp.stack([active_now, sleep_now, backoff_now, bar_now,
+                                 oc["grants"], oc["retires"], oc["fails"],
+                                 oc["enqueues"], wakes, cs["msgs"] - s["msgs"],
+                                 stall_now, loc_now, xcl_now,
+                                 qd.sum()]).astype(jnp.int32)
+                w = cyc // tele_cw
+                tele = s["tele"].at[w, :TELE_NSUM].add(row)
+                out["tele"] = tele.at[w, TELE_NSUM].max(qd.max())
         # completion trace: which micro-op (pre-advance pc) retired where,
         # how long it took from first acquire issue to retirement, plus
         # the per-cycle state/queue-depth traces behind Result.events()
         # and the Perfetto export (repro.obs)
-        ev = (dict(step=jnp.where(done, s["pc"], -1).astype(jnp.int32),
-                   wait=jnp.where(done, cyc - s["acq_start"],
-                                  -1).astype(jnp.int32),
-                   state=st.astype(jnp.int8), qlen=qd)
-              if p.record_trace else None)
+        with jax.named_scope("sim.account"):
+            ev = (dict(step=jnp.where(done, s["pc"], -1).astype(jnp.int32),
+                       wait=jnp.where(done, cyc - s["acq_start"],
+                                      -1).astype(jnp.int32),
+                       state=st.astype(jnp.int8), qlen=qd)
+                  if p.record_trace else None)
         return out, ev
 
     final, trace = lax.scan(step, state,
@@ -1122,10 +1151,13 @@ def execute(p: SimParams, energy_fit=None) -> Dict[str, np.ndarray]:
     dict.  Internal engine entry point: the supported public surface is
     :func:`repro.sync.run`, which wraps this in a typed
     :class:`repro.sync.Result`."""
-    out = _run(p)
-    res = {k: np.asarray(v) for k, v in out.items()}
-    return derive_metrics(res, min(p.n_workers, p.n_cores), p.cycles,
-                          energy_fit=energy_fit)
+    with span("repro.run.dispatch"):
+        out = _run(p)
+    with span("repro.run.fetch"):
+        res = {k: np.asarray(v) for k, v in out.items()}
+    with span("repro.run.metrics"):
+        return derive_metrics(res, min(p.n_workers, p.n_cores), p.cycles,
+                              energy_fit=energy_fit)
 
 
 def run(p: SimParams, energy_fit=None) -> Dict[str, np.ndarray]:

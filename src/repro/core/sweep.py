@@ -57,9 +57,9 @@ regenerate them.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import os
-import time
 import warnings
 from functools import partial
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -71,6 +71,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.sim import (DYN_FIELDS, _DENSE_BANK_ELTS, SimParams,
                             derive_metrics, simulate)
+from repro.obs.runreport import span
 
 #: fields that must match for configs to share one compilation — the
 #: workload's compiled program, the trace shape and the scan unroll
@@ -184,11 +185,13 @@ def sweep_iter(configs: Sequence[SimParams],
     flight instead of waiting on the full grid.
 
     ``report`` (a :class:`repro.obs.RunReport`) records per-chunk
-    compile/execute wall time and environment facts; when None, the
-    ambient report of an enclosing ``repro.obs.collect()`` block is
-    used (no-op when neither exists).  Instrumentation never changes
-    results — it only reads clocks around the existing dispatch and
-    transfer points.
+    compile/execute wall time, environment facts and the totals of the
+    host spans; when None, the ambient report of an enclosing
+    ``repro.obs.collect()`` block is used (no-op when neither exists).
+    The spans (``repro.sweep.dispatch`` / ``.drain`` / ``.metrics`` /
+    ``.isolate``, each with ``chunk=<index>``) also mark a profiler
+    trace.  Instrumentation never changes results — it only reads
+    clocks around the existing dispatch and transfer points.
 
     **Failure isolation:** a chunk that raises (at dispatch, execution
     or metric derivation) no longer kills the whole stream.  The
@@ -256,10 +259,11 @@ def sweep_iter(configs: Sequence[SimParams],
     def isolate(part, stage):
         """Bisected retry of a poisoned chunk: halves re-run batched,
         a failing half recurses, a single point falls through to
-        :func:`solo` — healthy points keep their normal results."""
+        :func:`solo` — healthy points keep their normal results.
+        Returns the ``(index, result)`` pairs."""
         if len(part) == 1:
-            yield part[0], solo(part[0], stage)
-            return
+            return [(part[0], solo(part[0], stage))]
+        pairs = []
         mid = len(part) // 2
         for half in (part[:mid], part[mid:]):
             chunk = [configs[i] for i in half]
@@ -271,28 +275,38 @@ def sweep_iter(configs: Sequence[SimParams],
                 out_h = jax.device_get(_sweep_group(rep_h, dyn_h,
                                                     len(chunk)))
             except Exception:        # noqa: BLE001 — fenced by design
-                yield from isolate(half, stage)
+                pairs += isolate(half, stage)
                 continue
-            for j, i in enumerate(half):
-                yield i, derive_checked(i, {k: v[j] for k, v in
-                                            out_h.items()}, stage)
+            pairs += [(i, derive_checked(i, {k: v[j] for k, v in
+                                             out_h.items()}, stage))
+                      for j, i in enumerate(half)]
+        return pairs
 
-    def materialize(part, out, rec):
+    def fenced(part, stage, ck):
+        # the span closes before the first point is yielded, so it
+        # times the ladder and not the consumer
+        with span("repro.sweep.isolate", report, chunk=ck):
+            pairs = isolate(part, stage)
+        yield from pairs
+
+    def materialize(ck, part, out, rec):
         # one device->host transfer per chunk (the whole result pytree)
-        t0 = time.perf_counter()
         try:
-            out_np = jax.device_get(out)
+            with span("repro.sweep.drain", report, chunk=ck) as took:
+                out_np = jax.device_get(out)
         except Exception:            # noqa: BLE001 — fenced by design
-            if rec is not None:
-                rec.execute_s = time.perf_counter() - t0
-            yield from isolate(part, "execute")
-            return
+            out_np = None
         if rec is not None:
             # async dispatch drains here, so this wall is execute time
-            rec.execute_s = time.perf_counter() - t0
+            rec.execute_s = took.seconds
+        if out_np is None:
+            yield from fenced(part, "execute", ck)
+            return
         for j, i in enumerate(part):             # padding rows never read
             res = {k: v[j] for k, v in out_np.items()}
-            yield i, derive_checked(i, res, "metrics")
+            with span("repro.sweep.metrics", report, chunk=ck):
+                m = derive_checked(i, res, "metrics")
+            yield i, m
 
     # dispatch chunks ahead of materialization: jax computations are
     # async, so the next chunk's host-side setup (and, with >1 device,
@@ -301,6 +315,7 @@ def sweep_iter(configs: Sequence[SimParams],
     # once — a record_trace point carries a (cycles, n) trace, so
     # unbounded dispatch would defeat the max_batch memory bound.
     window = 4
+    chunk_ids = itertools.count()
     for idxs in groups.values():
         grp = [configs[i] for i in idxs]
         # bank allocation = the group's power-of-two bucket (identical
@@ -317,6 +332,7 @@ def sweep_iter(configs: Sequence[SimParams],
         if an <= _DENSE_BANK_ELTS:
             chunk_cap = max(1, min(max_batch, _DENSE_BANK_ELTS // an))
         for lo in range(0, len(idxs), chunk_cap):
+            ck = next(chunk_ids)
             part = idxs[lo:lo + chunk_cap]
             chunk = [configs[i] for i in part]
             # pad the tail chunk to the full chunk length (and to a
@@ -339,17 +355,18 @@ def sweep_iter(configs: Sequence[SimParams],
                 else rep
             if sharding is not None:
                 dyn = jax.device_put(dyn, sharding)
-            t0 = time.perf_counter()
             cache_before = _sweep_group._cache_size() \
                 if report is not None else 0
             try:
-                out = _sweep_group(crep, dyn, len(padded),
-                                   None if sharding is None
-                                   else sharding.mesh)
+                with span("repro.sweep.dispatch", report,
+                          chunk=ck) as took:
+                    out = _sweep_group(crep, dyn, len(padded),
+                                       None if sharding is None
+                                       else sharding.mesh)
             except Exception:        # noqa: BLE001 — fenced by design
                 # poisoned at trace/compile time: fence it now, the
                 # stream keeps flowing
-                yield from isolate(part, "dispatch")
+                yield from fenced(part, "dispatch", ck)
                 continue
             rec = None
             if report is not None:
@@ -363,15 +380,15 @@ def sweep_iter(configs: Sequence[SimParams],
                            f"{crep.n_cores}c a{crep.n_addrs} "
                            f"{crep.cycles}cyc"),
                     points=len(part), batch=len(padded),
-                    compile_s=time.perf_counter() - t0, execute_s=0.0,
+                    compile_s=took.seconds, execute_s=0.0,
                     compiled=compiled,
                     devices=len(jax.tree.leaves(out)[0].sharding.device_set))
                 rec = report.chunks[-1]
-            pending.append((part, out, rec))
+            pending.append((ck, part, out, rec))
             if len(pending) >= window:
                 yield from materialize(*pending.pop(0))
-    for part, out, rec in pending:
-        yield from materialize(part, out, rec)
+    for chunk_args in pending:
+        yield from materialize(*chunk_args)
 
 
 def sweep_params(configs: Sequence[SimParams],

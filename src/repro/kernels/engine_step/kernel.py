@@ -227,6 +227,7 @@ def fused_step_call(proto, p, bank, *, cand_cyc, rot, addr, phase,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="engine_step",
     )(scal, *[x.reshape(1, n) for x in
               (cand_cyc, rot, addr, phase, acq_start,
                *[core[f] for f in core_names])],

@@ -17,8 +17,9 @@ end-of-run aggregates cannot:
   trace JSON loadable at https://ui.perfetto.dev.
 * **runner instrumentation** (:class:`RunReport`, :func:`collect`) —
   where did the sweep's wall time go?  Per-chunk compile vs execute
-  timing, backend/device facts, persistent-cache hits; ambient
-  collection via ``with obs.collect() as report:``.
+  timing, backend/device facts, persistent-cache hits, and the totals
+  of the host spans (:func:`span`) that also mark a profiler trace;
+  ambient collection via ``with obs.collect() as report:``.
 
 Submodules import lazily (PEP 562), so the engine's dependency on
 ``repro.obs.schema`` stays one light leaf module.
@@ -28,12 +29,13 @@ from __future__ import annotations
 from typing import TYPE_CHECKING
 
 __all__ = ["schema", "Timeseries", "EventLog", "Span", "RunReport",
-           "ChunkRecord", "collect", "current", "perfetto"]
+           "ChunkRecord", "collect", "current", "span", "perfetto"]
 
 if TYPE_CHECKING:                     # pragma: no cover - typing only
     from repro.obs import perfetto, schema
     from repro.obs.events import EventLog, Span
-    from repro.obs.runreport import ChunkRecord, RunReport, collect, current
+    from repro.obs.runreport import (ChunkRecord, RunReport, collect,
+                                     current, span)
     from repro.obs.timeseries import Timeseries
 
 #: attribute -> (submodule, member or None for the module itself)
@@ -47,6 +49,7 @@ _LAZY = {
     "ChunkRecord": ("repro.obs.runreport", "ChunkRecord"),
     "collect": ("repro.obs.runreport", "collect"),
     "current": ("repro.obs.runreport", "current"),
+    "span": ("repro.obs.runreport", "span"),
 }
 
 

@@ -28,6 +28,28 @@ or explicit: ``Study.run(report=report)`` / ``Study.stream(report=...)``
 / ``sweep_iter(..., report=report)``.
 
 Persistent-cache hits are counted through ``jax.monitoring`` events.
+
+Host spans: :func:`span` names a stretch of host work.  Each opens a
+``jax.profiler.TraceAnnotation`` and, while a report is collecting,
+adds its wall time and a count to ``RunReport.spans``.  ``repro.sync.run``
+enters ``repro.run`` (the whole call) and, inside it,
+``repro.run.dispatch`` (the jitted call: argument hashing, cache lookup,
+enqueue, compile on a miss), ``repro.run.fetch`` (waiting for the device
+and the transfer) and ``repro.run.metrics`` (metric derivation).  The
+sweep enters ``repro.sweep.dispatch``, ``repro.sweep.drain`` and
+``repro.sweep.metrics`` (per point) with ``chunk=<index>``, and
+``repro.sweep.isolate`` when a chunk fails.  On the device, each stage
+of the engine's scan body runs under a ``jax.named_scope`` (``sim.issue``
+... ``sim.faults``, see ``repro.core.sim.simulate``).  To see both, run
+under the profiler and open the trace (TensorBoard or Perfetto)::
+
+    import jax
+    with jax.profiler.trace("/tmp/prof"):
+        repro.sync.run(spec)
+
+The host spans are the ``repro.*`` events of the host plane; the device
+ops carry their stage in the compiled program's ``op_name`` metadata
+(``bench/stages.py`` maps traced ops to stages).
 """
 from __future__ import annotations
 
@@ -35,6 +57,8 @@ import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: the active ambient report (see :func:`collect` / :func:`current`)
 _current: Optional["RunReport"] = None
@@ -88,7 +112,8 @@ class RunReport:
     max_batch: Optional[int] = None
     chunks: List[ChunkRecord] = dataclasses.field(default_factory=list)
     persistent_cache_hits: int = 0
-    started_at: float = dataclasses.field(default_factory=time.time)
+    #: host span name -> [total seconds, count] (see :func:`span`)
+    spans: Dict[str, List[float]] = dataclasses.field(default_factory=dict)
 
     # ---- recording (called by repro.core.sweep) -------------------------
     def note_env(self, backend: str, max_batch: int) -> None:
@@ -150,7 +175,8 @@ class RunReport:
                 "n_compiles": self.n_compiles,
                 "compile_s": self.compile_s, "execute_s": self.execute_s,
                 "persistent_cache_hits": self.persistent_cache_hits,
-                "chunks": [c.to_dict() for c in self.chunks]}
+                "chunks": [c.to_dict() for c in self.chunks],
+                "spans": {k: list(v) for k, v in self.spans.items()}}
 
 
 def current() -> Optional[RunReport]:
@@ -174,3 +200,30 @@ def collect(report: Optional[RunReport] = None):
         yield rep
     finally:
         _current = prev
+
+
+@dataclasses.dataclass
+class SpanTime:
+    """Wall seconds of one :func:`span`, set when the span closes."""
+    seconds: float = 0.0
+
+
+@contextlib.contextmanager
+def span(name: str, report: Optional[RunReport] = None, **args: Any):
+    """Name a stretch of host work: a ``jax.profiler.TraceAnnotation``
+    (``args`` become its arguments, e.g. ``chunk=3``), plus its wall time
+    and a count in ``report.spans`` (``report`` defaults to the ambient
+    one; with neither, nothing is recorded).  Yields a :class:`SpanTime`
+    whose ``seconds`` is set on exit, exceptions included."""
+    rep = report if report is not None else _current
+    took = SpanTime()
+    with TraceAnnotation(name, **args):
+        t0 = time.perf_counter()
+        try:
+            yield took
+        finally:
+            took.seconds = time.perf_counter() - t0
+            if rep is not None:
+                tot = rep.spans.setdefault(name, [0.0, 0])
+                tot[0] += took.seconds
+                tot[1] += 1

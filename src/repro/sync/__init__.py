@@ -48,6 +48,7 @@ from repro.core import workloads as _workloads
 from repro.core import sim as _sim
 from repro.core.metrics import METRIC_TRIPLE
 from repro.core.sweep import enable_persistent_cache
+from repro.obs.runreport import span
 from repro.sync.result import Result
 from repro.sync.spec import Costs, Protocol, Spec, Topology, Workload
 from repro.sync.study import Study
@@ -67,16 +68,17 @@ def run(spec: Optional[Spec] = None, *, energy_fit=None,
     overrides the frozen Table II calibration behind
     ``energy_pj_per_op``.
     """
-    if spec is None:
-        spec = Spec(**flat)
-    else:
-        if isinstance(spec, dict):
-            spec = Spec.from_dict(spec)
-        if flat:
-            spec = spec.replace(**flat)
-    return Result(spec=spec,
-                  stats=_sim.execute(spec.to_params(),
-                                     energy_fit=energy_fit))
+    with span("repro.run"):
+        if spec is None:
+            spec = Spec(**flat)
+        else:
+            if isinstance(spec, dict):
+                spec = Spec.from_dict(spec)
+            if flat:
+                spec = spec.replace(**flat)
+        return Result(spec=spec,
+                      stats=_sim.execute(spec.to_params(),
+                                         energy_fit=energy_fit))
 
 
 def protocols() -> Tuple[str, ...]:

@@ -17,6 +17,8 @@ only one process may hold the TPU library, and every test worker imports
 this file.
 """
 import dataclasses
+import importlib.util
+import os
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ import pytest
 from repro.core import protocols, sim, sweep
 
 CYCLES = 64
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -123,3 +126,39 @@ def test_sweep_lowers_sharded_over_four_chips(tpu_params, topo):
     assert "all-gather" not in c.as_text()
     out = jax.tree.leaves(c.output_shardings)[0]
     assert len(out.device_set) == 4
+
+
+def _stages():
+    """``bench/stages.py``: maps a compiled program's ops to the engine's
+    stages, the way a profile of the chip is read."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_stages", os.path.join(ROOT, "bench", "stages.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_every_loop_op_resolves_to_a_stage(tpu_params, topo):
+    """The 1024-core ``cluster2`` machine of the benchmark's rmw_hot4
+    cell: every fusion, reduce-window and custom call of the scan body
+    maps to a ``sim.*`` stage.  The rotating priority's cumsum lowers to
+    reduce-windows with a bare op name, found through the dataflow; the
+    kernel is ``sim.arbitrate`` and named ``engine_step``."""
+    from jax.sharding import SingleDeviceSharding
+    st = _stages()
+    p = tpu_params(protocol="colibri_hier", n_cores=1024, n_addrs=4,
+                   topology="cluster2", clusters=4)
+    text = _compile_run(p, SingleDeviceSharding(topo.devices[0])).as_text()
+    smap = st.stage_map(text)
+    heavy = [i for i in st.loop_body(text)
+             if i.opcode in ("fusion", "reduce-window", "custom-call")]
+    assert len(heavy) > 20
+    assert {i.name: smap[i.name] for i in heavy
+            if not smap[i.name].startswith("sim.")} == {}
+    windows = [i for i in heavy if i.opcode == "reduce-window"]
+    assert windows and all(i.scope is None for i in windows)
+    assert {smap[i.name] for i in windows} == {"sim.network"}
+    kernels = [i for i in heavy if "tpu_custom_call" in i.text]
+    assert len(kernels) == 1
+    assert smap[kernels[0].name] == "sim.arbitrate"
+    assert kernels[0].name.startswith("engine_step")
