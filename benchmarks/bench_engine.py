@@ -125,7 +125,7 @@ def rows() -> List[Dict]:
     # hierarchical-topology overhead: the same engine run with the
     # cluster2 network stage on (per-level link caps + hop billing) —
     # the flat row above is the in-benchmark baseline for the cost of
-    # the topology tables
+    # the topology lookup
     n_topo = min(256, max(ENGINE_CORES))
     s = Spec(protocol="colibri", n_cores=n_topo, cycles=ENGINE_CYCLES,
              topology="cluster2", clusters=4)

@@ -148,6 +148,15 @@ _DENSE_BANK_ELTS = 32768
 #: static hint and also bounds the batched element count.
 _DENSE_BATCH_ELTS = 131072
 
+#: bank count up to which a hierarchical topology looks up each lane's
+#: bank-side cluster ids (``bank_lvl[ℓ][addr]``, once per cycle) by an
+#: unrolled select over the banks against constants; above it, by one
+#: gather per level from the (a,) table.  Measured on a TPU v5e at 1024
+#: cores (cluster2): select ties the gather at a = 16..64 and wins by
+#: 16% of the whole cycle at a = 128; at a = 256 it still won 14% per
+#: cycle but compiled 9 s slower (18 s against 9), at 1024 it won 3%.
+_TOPO_SELECT_BANKS = 128
+
 
 @dataclasses.dataclass(frozen=True)
 class SimParams:
@@ -192,7 +201,7 @@ class SimParams:
     # crossbar and compiles to NO topology tables at all — the trace is
     # bit-identical to the pre-topology engine (tests/test_topology.py
     # pins the full protocol × workload grid).  Hierarchical entries
-    # ("cluster2", "cluster3") close per-(core,bank) hop/latency tables
+    # ("cluster2", "cluster3") close per-level core and bank cluster ids
     # and per-level link budgets over the scan as constants: the carry
     # contract gains only the single ``hops`` counter.
     topology: str = "flat"
@@ -346,6 +355,16 @@ def fifo_bank_winners(arrived: jnp.ndarray, arr_cyc: jnp.ndarray,
     return _fifo_lex_best(arrived, arr_cyc, rot, addr, a)[0]
 
 
+def topo_lookup(p: SimParams) -> str:
+    """How ``simulate`` looks up the bank side of ``p``'s topology:
+    ``"none"`` (flat: no lookup traced), ``"select"`` or ``"gather"``
+    (by the static bank count, see ``_TOPO_SELECT_BANKS``).  The
+    dispatch spans and ``ChunkRecord.topo`` record it."""
+    if not topo_registry.get(p.topology).levels:
+        return "none"
+    return "select" if p.n_addrs <= _TOPO_SELECT_BANKS else "gather"
+
+
 def _resolve(p: SimParams, dyn: Optional[Dict] = None) -> SimpleNamespace:
     """Parameter namespace handed to the engine and plugins.  Fields named
     in ``dyn`` become traced scalars; everything else stays a Python int
@@ -380,14 +399,15 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
     q_cap = proto.q_cap(p, n)
     exp_cap = 1 if proto.fixed_backoff else rp.backoff_exp
     # ---- NoC topology (core.topologies) --------------------------------
-    # Placement/hop/latency tables are compiled host-side ONCE per trace
-    # and closed over as constants — same carry-cliff discipline as
-    # telemetry/faults: ``flat`` compiles to is_flat and every topology
-    # branch below is Python-gated off, tracing to exactly the
-    # pre-topology jaxpr (tests/test_topology.py pins bit-identity).
+    # The per-level core and bank cluster ids are compiled host-side ONCE
+    # per trace and closed over as constants — same carry-cliff
+    # discipline as telemetry/faults: ``flat`` compiles to is_flat and
+    # every topology branch below is Python-gated off, tracing to exactly
+    # the pre-topology jaxpr (tests/test_topology.py pins bit-identity).
     topo = topo_registry.get(p.topology)
     tt = topo.tables(p, n, a)
     use_topo = not tt.is_flat
+    lookup = topo_lookup(p)
 
     state = dict(
         st=jnp.full((n,), WORK, jnp.int32),
@@ -483,12 +503,9 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
     iota = jnp.arange(n, dtype=jnp.int32)
     ba = jnp.arange(a, dtype=jnp.int32)
     if use_topo:
-        # flattened (n*a,) tables: the scan body gathers one lane per
-        # core at ``iota * a + addr`` (addr is finalized before every
-        # consumer) — two O(n) gathers per cycle, no scatters
-        extra_t = jnp.asarray(tt.extra.reshape(-1), jnp.int32)
-        hops_t = jnp.asarray(tt.hops.reshape(-1), jnp.int32)
-        cross_t = tuple(jnp.asarray(x.reshape(-1)) for x in tt.cross)
+        # a core's cluster id per level is a per-lane constant; only the
+        # bank side depends on ``addr``
+        core_lvl = tuple(jnp.asarray(c) for c in tt.core_lvl)
         lvl_div = tuple(lv.bw_div for lv in topo.levels)
     is_worker = iota < rp.n_workers              # first W cores are workers
     # static: worker machinery folds away when no config has workers
@@ -534,6 +551,25 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
         if has_zipf:
             out = jnp.where(mode_is_zipf[pc],
                             zipf_index(h, rp.n_addrs, rp.zipf_skew), out)
+        return out
+
+    def crossings(addr):
+        """Per level, whether each lane's (core, ``addr``) path crosses
+        it: ``core_lvl[ℓ] != bank_lvl[ℓ][addr]``.  The bank-side id is a
+        select chain over the banks (starting from the commonest id, so
+        banks holding it cost nothing) or, past ``_TOPO_SELECT_BANKS``,
+        one gather from the (a,) table."""
+        out = []
+        for cl, bl in zip(core_lvl, tt.bank_lvl):
+            if lookup == "select":
+                vals, cnt = np.unique(bl, return_counts=True)
+                base = int(vals[np.argmax(cnt)])
+                b_id = jnp.full(addr.shape, base, jnp.int32)
+                for b in np.flatnonzero(bl != base):
+                    b_id = jnp.where(addr == int(b), int(bl[b]), b_id)
+            else:
+                b_id = jnp.asarray(bl)[addr]
+            out.append(cl != b_id)
         return out
 
     # Each stage of a simulated cycle runs under a ``jax.named_scope``
@@ -593,13 +629,17 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                               jnp.where(start | rb, P_ACQ, s["phase"]))
             st = jnp.where(issue, REQ, st)
             if use_topo:
-                # cross-cluster requests pay the per-level extra latency once
+                # ``addr`` is final from here on: look up its crossings once
+                # for the extra latency, link budgets, hops and telemetry.
+                # Cross-cluster requests pay the per-level extra latency once
                 # per issue (acquire, reissue, release) — the round-trip cost
                 # of the level routers on top of the flat ``lat`` baseline.
                 # Billed HERE, before the request reaches the network/bank
                 # stages, so protocols and the Pallas kernel never see
                 # topology: backends stay bit-identical by construction.
-                tmr = jnp.where(issue, rp.lat + extra_t[iota * a + addr], tmr)
+                cm = crossings(addr)
+                x_lat = sum(lat * x for lat, x in zip(tt.extra_lat, cm))
+                tmr = jnp.where(issue, rp.lat + x_lat, tmr)
             else:
                 tmr = jnp.where(issue, rp.lat, tmr)
 
@@ -703,12 +743,12 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                 # cycle; they count into net_stall like any denied request.
                 # Worker streams stay cluster-local (their banks are the
                 # local SPM ports), so only atomic requests contend here.
-                xmask = [lx[iota * a + addr] & ~is_worker for lx in cross_t]
-                for cm, div in zip(xmask, lvl_div):
+                xmask = [x & ~is_worker for x in cm]
+                for xm, div in zip(xmask, lvl_div):
                     acc_x = accept_rotating_fair(
-                        all_req & cm, rot, jnp.maximum(rp.net_bw // div, 1),
+                        all_req & xm, rot, jnp.maximum(rp.net_bw // div, 1),
                         shift=shift)
-                    accepted = accepted & (~cm | acc_x)
+                    accepted = accepted & (~xm | acc_x)
             # Bernoulli NoC drop on newly-accepted requests: the message
             # dies in flight, the core stays in REQ and retransmits next
             # cycle; the wasted link hop is billed into msgs below
@@ -737,9 +777,10 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                 # request traverses its (core, bank) hop path twice (request
                 # + response); accepted worker loads are cluster-local
                 # single-hop round trips.
+                hops_lane = 1 + 2 * sum(x.astype(jnp.int32) for x in cm)
                 hops_cnt = (s["hops"]
                             + 2 * jnp.where(fresh & accepted,
-                                            hops_t[iota * a + addr], 0).sum()
+                                            hops_lane, 0).sum()
                             + 2 * w_acc.sum())
 
         # ---- bank arbitration: FIFO by arrival stamp among parked ----
@@ -1151,7 +1192,7 @@ def execute(p: SimParams, energy_fit=None) -> Dict[str, np.ndarray]:
     dict.  Internal engine entry point: the supported public surface is
     :func:`repro.sync.run`, which wraps this in a typed
     :class:`repro.sync.Result`."""
-    with span("repro.run.dispatch"):
+    with span("repro.run.dispatch", topo=topo_lookup(p)):
         out = _run(p)
     with span("repro.run.fetch"):
         res = {k: np.asarray(v) for k, v in out.items()}

@@ -12,10 +12,11 @@ Importing this package registers every built-in topology:
                group boundary (+12 / ÷8)
 =============  ==========================================================
 
-A topology compiles ``(p, n_cores, n_addrs)`` into static per-(core,
-bank) hop/latency tables plus per-level link-crossing masks
-(:class:`~repro.core.topologies.base.TopoTables`) that the engine's
-network stage closes over as constants — the scan carry contract is
+A topology compiles ``(p, n_cores, n_addrs)`` into static per-level
+core and bank cluster ids
+(:class:`~repro.core.topologies.base.TopoTables`) from which the
+engine derives each lane's link-crossing mask, hop count and extra
+latency, closed over as constants — the scan carry contract is
 untouched and mixed-topology sweeps chunk per compile group like any
 other static field.
 

@@ -8,23 +8,30 @@ cluster NoC with per-level latencies and per-level link bandwidth.  A
 :class:`Topology` plugin describes that shape declaratively — a cluster
 tree with per-level extra latency and link capacity, plus a placement
 rule mapping cores and banks onto clusters — and *compiles* it into
-static per-(core, bank) tables the engine's network stage consumes:
+the static factored form the engine's network stage consumes: per
+boundary level ``ℓ``, the cluster id of every core (``core_lvl[ℓ]``,
+``(n,)``) and of every bank (``bank_lvl[ℓ]``, ``(a,)``), plus the
+levels' extra latencies.  Everything per (core, bank) follows from it:
 
+* ``cross[ℓ][c, b]`` — whether a ``c → b`` message crosses level ``ℓ``'s
+  boundary, ``core_lvl[ℓ][c] != bank_lvl[ℓ][b]``; crossing messages
+  contend for that level's per-cycle link budget (``net_bw // bw_div``)
+  on top of the global acceptance budget;
 * ``hops[c, b]``   — NoC hop count of a ``c → b`` request (1 for a
   bank in the core's own cluster, +2 per crossed level: up through the
   level router and back down);
 * ``extra[c, b]``  — round-trip extra latency in cycles beyond the flat
-  ``lat`` baseline, billed once at request issue;
-* ``cross[ℓ][c, b]`` — whether a ``c → b`` message crosses level ``ℓ``'s
-  boundary; crossing messages contend for that level's per-cycle link
-  budget (``net_bw // bw_div``) on top of the global acceptance budget.
+  ``lat`` baseline, billed once at request issue.
 
-The tables are plain numpy, computed once per trace and closed over as
-constants — the engine's ``lax.scan`` carry contract is untouched, and
-the ``flat`` topology compiles to the *absence* of tables
-(:attr:`TopoTables.is_flat`), so the engine Python-gates every topology
-branch off and traces to exactly the pre-topology jaxpr (the telemetry/
-faults static-elision discipline, audited by ``repro.analysis``).
+The engine looks up each level's bank-side id of a lane's bank once per
+cycle and derives all three per lane; the dense ``(n, a)`` views exist
+for the property tests.  The arrays are plain numpy, computed once per
+trace and closed over as constants — the engine's ``lax.scan`` carry
+contract is untouched, and the ``flat`` topology compiles to the
+*absence* of levels (:attr:`TopoTables.is_flat`), so the engine
+Python-gates every topology branch off and traces to exactly the
+pre-topology jaxpr (the telemetry/faults static-elision discipline,
+audited by ``repro.analysis``).
 """
 from __future__ import annotations
 
@@ -50,14 +57,38 @@ class LinkLevel:
 
 @dataclasses.dataclass(frozen=True)
 class TopoTables:
-    """Compiled per-(core, bank) tables for one (topology, n, a, clusters)
-    point.  All arrays are numpy (trace-time constants)."""
-    hops: np.ndarray                      # (n, a) int32, >= 1
-    extra: np.ndarray                     # (n, a) int32, >= 0
-    cross: Tuple[np.ndarray, ...]         # per level: (n, a) bool
+    """Compiled placement of one (topology, n, a, clusters) point, in
+    factored form.  All arrays are numpy (trace-time constants)."""
+    core_lvl: Tuple[np.ndarray, ...]      # per level: (n,) int32 cluster id
+    bank_lvl: Tuple[np.ndarray, ...]      # per level: (a,) int32 cluster id
+    extra_lat: Tuple[int, ...]            # per level: round-trip extra cycles
     core_cluster: np.ndarray              # (n,) int32 leaf-cluster of core
     bank_cluster: np.ndarray              # (a,) int32 leaf-cluster of bank
-    is_flat: bool                         # no levels: engine gates all off
+
+    @property
+    def is_flat(self) -> bool:
+        """No levels: the engine gates every topology branch off."""
+        return not self.core_lvl
+
+    # ---- dense (n, a) views ---------------------------------------------
+    @property
+    def cross(self) -> Tuple[np.ndarray, ...]:
+        """Per level: (n, a) bool, whether ``c → b`` crosses it."""
+        return tuple(cl[:, None] != bl[None, :]
+                     for cl, bl in zip(self.core_lvl, self.bank_lvl))
+
+    @property
+    def hops(self) -> np.ndarray:
+        """(n, a) int32 hop count, 1 + 2 per crossed level."""
+        shape = (self.core_cluster.size, self.bank_cluster.size)
+        return 1 + 2 * sum(self.cross, np.zeros(shape, np.int32))
+
+    @property
+    def extra(self) -> np.ndarray:
+        """(n, a) int32 extra latency of the crossed levels."""
+        shape = (self.core_cluster.size, self.bank_cluster.size)
+        return sum((lat * x for lat, x in zip(self.extra_lat, self.cross)),
+                   np.zeros(shape, np.int32)).astype(np.int32)
 
 
 def cluster_of(idx: np.ndarray, count: int, clusters: int) -> np.ndarray:
@@ -100,24 +131,19 @@ class Topology:
 
     # ---- compilation ----------------------------------------------------
     def tables(self, p, n: int, a: int) -> TopoTables:
-        """Compile the placement + level declarations into the static
-        per-(core, bank) hop/latency/crossing tables."""
+        """Compile the placement + level declarations into the factored
+        per-level core and bank cluster ids."""
         cc = np.asarray(self.core_clusters(p, n), np.int32)
         bc = np.asarray(self.bank_clusters(p, a), np.int32)
         if cc.shape != (n,) or bc.shape != (a,):
             raise ValueError(
                 f"topology {self.name!r}: placement shapes {cc.shape}/"
                 f"{bc.shape} do not match (n={n}, a={a})")
-        hops = np.ones((n, a), np.int32)
-        extra = np.zeros((n, a), np.int32)
-        cross = []
-        for lv, spec in enumerate(self.levels):
-            cl = self.level_cluster(cc, lv, p)[:, None]
-            bl = self.level_cluster(bc, lv, p)[None, :]
-            x = cl != bl
-            cross.append(x)
-            hops = hops + 2 * x.astype(np.int32)
-            extra = extra + spec.extra_lat * x.astype(np.int32)
-        return TopoTables(hops=hops, extra=extra, cross=tuple(cross),
-                          core_cluster=cc, bank_cluster=bc,
-                          is_flat=not self.levels)
+        lv = range(len(self.levels))
+        return TopoTables(
+            core_lvl=tuple(np.asarray(self.level_cluster(cc, i, p), np.int32)
+                           for i in lv),
+            bank_lvl=tuple(np.asarray(self.level_cluster(bc, i, p), np.int32)
+                           for i in lv),
+            extra_lat=tuple(spec.extra_lat for spec in self.levels),
+            core_cluster=cc, bank_cluster=bc)

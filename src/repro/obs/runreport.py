@@ -98,6 +98,7 @@ class ChunkRecord:
     execute_s: float      # materialize wall: device_get drain
     compiled: bool        # this dispatch built a new in-process executable
     devices: int = 1      # devices the chunk's result batch is sharded over
+    topo: str = "none"    # topology lookup traced: none | select | gather
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -130,11 +131,13 @@ class RunReport:
 
     def record_chunk(self, label: str, points: int, batch: int,
                      compile_s: float, execute_s: float,
-                     compiled: bool, devices: int = 1) -> None:
+                     compiled: bool, devices: int = 1,
+                     topo: str = "none") -> None:
         self.chunks.append(ChunkRecord(label=label, points=points,
                                        batch=batch, compile_s=compile_s,
                                        execute_s=execute_s,
-                                       compiled=compiled, devices=devices))
+                                       compiled=compiled, devices=devices,
+                                       topo=topo))
 
     # ---- aggregates -----------------------------------------------------
     @property

@@ -151,6 +151,33 @@ def test_study_spans_feed_the_chunk_records():
     assert "repro.sweep.isolate" not in rep.spans
 
 
+def test_dispatch_spans_and_chunks_name_the_topology_lookup(monkeypatch):
+    """Every run and chunk says which topology lookup it traced: the
+    dispatch spans carry ``topo=`` and each chunk record keeps it."""
+    from repro.obs import runreport
+    seen = []
+
+    def annotate(name, **args):
+        if name.endswith(".dispatch"):
+            seen.append((name, args["topo"]))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(runreport, "TraceAnnotation", annotate)
+    base = Spec(protocol="colibri", n_cores=16, costs={"cycles": 200})
+    wide = 2 * sim._TOPO_SELECT_BANKS
+    points = [base.replace(n_addrs=4),
+              base.replace(topology="cluster2", n_addrs=4),
+              base.replace(topology="cluster2", n_addrs=wide)]
+    with obs.collect() as rep:
+        assert run(points[1]).ok
+        assert all(r.ok for r in Study.from_specs(points).run())
+    forms = ["none", "select", "gather"]
+    assert seen == ([("repro.run.dispatch", "select")]
+                    + [("repro.sweep.dispatch", f) for f in forms])
+    assert [c.topo for c in rep.chunks] == forms
+    assert [c["topo"] for c in rep.to_dict()["chunks"]] == forms
+
+
 def test_failed_chunk_records_an_isolate_span(monkeypatch):
     orig = sweep_mod._sweep_group
 

@@ -4,6 +4,10 @@ integration.
 
 The contract under test, in cost order:
 
+* **the factored form rebuilds the tables** — the per-level core and
+  bank cluster ids the engine consumes give back exactly the per-(core,
+  bank) crossing masks, hop counts and extra latencies the placement
+  hooks define, on both sides of the engine's select/gather crossover;
 * **the tables are a lawful cover** — for every registered topology and
   any (n, a, clusters) shape, each (core, bank) pair gets exactly one
   hop path (the compile is deterministic and total), hop counts are odd
@@ -15,6 +19,10 @@ The contract under test, in cost order:
 * **flat is free** — under ``topology="flat"`` the ``clusters`` knob is
   statically irrelevant: every protocol × workload point is
   bit-identical across cluster settings, and no ``hops`` stat appears;
+* **both lookup forms are bit-identical to the table engine** — pinned
+  goldens of ``hops``/``ops``/``lat_hist``/``net_stall``/``polls``
+  recorded from the engine that gathered from (n·a) tables, and no
+  per-lane gather from an (n·a) table in the scan body;
 * **clusters are backend-agnostic** — the Pallas fused-step path never
   sees the topology (extra latency is billed once at issue, link caps
   run in the engine's network stage), so xla_cpu and pallas_interpret
@@ -27,8 +35,10 @@ The contract under test, in cost order:
   through grants, parks, and watchdog evictions — the invariant the
   model checker certifies, exercised here directly on the hooks.
 """
+import dataclasses
 import types
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -36,6 +46,7 @@ import pytest
 from repro.core import protocols, topologies, workloads
 from repro.core.protocols.base import (OUT_EVICT, OUT_GRANT, OUT_SLEEP,
                                        Ctx, FusedCtx)
+from repro.core import sim
 from repro.core.sim import SimParams, _run
 from repro.core.topologies import LinkLevel, Topology, base as topo_base
 from repro.core.topologies import registry as topo_registry
@@ -133,12 +144,32 @@ def _check_tables(topo, clusters: int, n: int, a: int) -> None:
     assert t.is_flat == (not topo.levels)
     if t.is_flat:
         assert (t.hops == 1).all() and (t.extra == 0).all()
+    # factoring: the per-level ids the engine consumes rebuild, pair by
+    # pair, what the placement hooks define
+    assert len(t.core_lvl) == len(t.bank_lvl) == len(topo.levels)
+    assert t.extra_lat == tuple(lv.extra_lat for lv in topo.levels)
+    cc, bc = topo.core_clusters(p, n), topo.bank_clusters(p, a)
+    hops, extra = np.ones((n, a), np.int64), np.zeros((n, a), np.int64)
+    for lv, (cl, bl, x) in enumerate(zip(t.core_lvl, t.bank_lvl, t.cross)):
+        assert cl.shape == (n,) and bl.shape == (a,)
+        want = (topo.level_cluster(cc, lv, p)[:, None]
+                != topo.level_cluster(bc, lv, p)[None, :])
+        np.testing.assert_array_equal(cl[:, None] != bl[None, :], want)
+        np.testing.assert_array_equal(x, want)
+        hops += 2 * want
+        extra += topo.levels[lv].extra_lat * want
+    np.testing.assert_array_equal(t.hops, hops)
+    np.testing.assert_array_equal(t.extra, extra)
 
 
 def test_tables_property_seeded_sweep():
     rng = np.random.default_rng(20240808)
     shapes = [(2, 1, 1), (2, 1, 2), (4, 2, 2), (5, 3, 2), (16, 4, 4),
               (33, 7, 4), (64, 16, 8), (256, 16, 4)]
+    # both sides of the engine's select/gather crossover
+    x = sim._TOPO_SELECT_BANKS
+    shapes += [(64, x, 4), (64, x + 1, 8), (96, 2 * x + 3, 16),
+               (1024, 4, 4), (128, 8 * x, 32)]
     shapes += [(int(rng.integers(2, 129)), int(rng.integers(1, 33)),
                 int(rng.integers(1, 17))) for _ in range(40)]
     for n, a, clusters in shapes:
@@ -187,6 +218,93 @@ def test_flat_bit_identical_across_cluster_knob(protocol):
             np.testing.assert_array_equal(
                 np.asarray(r1[k]), np.asarray(r4[k]),
                 err_msg=f"{protocol}/{wl}: field {k!r} diverged")
+
+
+# ---------------------------------------------------------------------------
+# the engine's per-cycle lookup: both forms bit-identical to the tables
+# ---------------------------------------------------------------------------
+
+# recorded from the engine that gathered hops/extra/cross at
+# ``iota * a + addr`` from flattened (n·a) tables (zipf_histogram,
+# net_bw=8, so the link budgets bite); lat_hist as {bin: count}
+LOOKUP_GOLDEN = {
+    "select": (
+        dict(protocol="colibri_hier", topology="cluster2", clusters=4,
+             n_addrs=4, seed=11),
+        dict(hops=3420, net_stall=550, polls=0,
+             ops=[4, 4, 10, 8, 6, 3, 3, 10, 8, 5, 3, 11, 10, 7, 5, 4, 3, 9,
+                  6, 4, 3, 7, 8, 6, 3, 3, 9, 8, 5, 3, 3, 9, 7, 4, 2, 1, 8, 6,
+                  4, 1, 1, 8, 6, 3, 1, 1, 8, 5, 2, 2, 8, 6, 4, 2, 2, 8, 5, 4,
+                  1, 2, 7, 4, 3, 2],
+             lat_hist={17: 4, 18: 6, 19: 2, 20: 6, 21: 54, 22: 16, 23: 31,
+                       24: 10, 25: 22, 26: 20, 27: 7, 28: 14, 29: 8, 30: 15,
+                       31: 23, 32: 16, 33: 14, 34: 2, 35: 2, 36: 12, 37: 3,
+                       38: 17, 39: 14})),
+    "gather": (
+        dict(protocol="colibri", topology="cluster3", clusters=8,
+             n_addrs=256, seed=12),
+        dict(hops=10362, net_stall=5369, polls=0,
+             ops=[8, 6, 3, 12, 10, 7, 5, 14, 11, 9, 7, 15, 13, 11, 8, 9, 4,
+                  9, 10, 8, 11, 3, 12, 9, 10, 5, 14, 11, 9, 7, 12, 4, 11, 8,
+                  6, 13, 6, 10, 7, 12, 3, 12, 9, 7, 5, 14, 11, 9, 6, 4, 13,
+                  11, 8, 6, 15, 12, 10, 7, 12, 6, 12, 9, 8, 13],
+             lat_hist={18: 57, 19: 4, 20: 35, 21: 13, 22: 6, 23: 229, 24: 51,
+                       25: 87, 26: 27, 27: 20, 28: 5, 29: 4, 30: 2, 32: 2,
+                       33: 5, 34: 7, 35: 4, 36: 5, 37: 7, 38: 5, 39: 6})),
+}
+
+
+@pytest.mark.parametrize("form", sorted(LOOKUP_GOLDEN))
+def test_lookup_forms_match_table_goldens(form):
+    cfg, want = LOOKUP_GOLDEN[form]
+    p = SimParams(workload="zipf_histogram", n_cores=64, cycles=1500,
+                  net_bw=8, **cfg)
+    assert sim.topo_lookup(p) == form
+    r = _run(p)
+    lh = np.asarray(r["lat_hist"])
+    got = dict(hops=int(r["hops"]), net_stall=int(r["net_stall"]),
+               polls=int(r["polls"]), ops=np.asarray(r["ops"]).tolist(),
+               lat_hist={int(i): int(lh[i]) for i in np.flatnonzero(lh)})
+    assert got == want
+
+
+def _scan_body_gathers(p):
+    """(operand size, result size) of every gather in the scan body."""
+    jx = jax.make_jaxpr(lambda: sim.simulate(p))()
+    body = next(e for e in jx.jaxpr.eqns
+                if e.primitive.name == "scan").params["jaxpr"].jaxpr
+
+    def walk(j):
+        for e in j.eqns:
+            yield e
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+    return [(e.invars[0].aval.size, e.outvars[0].aval.size)
+            for e in walk(body) if e.primitive.name == "gather"]
+
+
+@pytest.mark.parametrize("topology,a,form",
+                         [("cluster2", 4, "select"),
+                          ("cluster3", 2 * sim._TOPO_SELECT_BANKS, "gather")])
+def test_scan_body_has_no_per_lane_table_gather(topology, a, form):
+    """The per-(core, bank) tables are never gathered per lane again:
+    no gather in the scan body reads an operand of n·a elements into n
+    lanes (the table engine had three for cluster2, four for cluster3);
+    the gather form reads one (a,) table per level."""
+    n = 1024
+    p = SimParams(protocol="colibri_hier", topology=topology, clusters=8,
+                  n_cores=n, n_addrs=a, cycles=8)
+    assert sim.topo_lookup(p) == form
+    gathers = _scan_body_gathers(p)
+    assert (n * a, n) not in gathers
+    levels = len(topologies.get(topology).levels)
+    flat = _scan_body_gathers(dataclasses.replace(p, topology="flat"))
+    extra = [g for g in gathers if g == (a, n)]
+    assert len(extra) - flat.count((a, n)) == (
+        levels if form == "gather" else 0)
 
 
 # ---------------------------------------------------------------------------
