@@ -64,6 +64,7 @@ from repro.core.workloads.base import (ADDR_FIXED, ADDR_ZIPF, K_BARRIER,
                                        zipf_index)
 from repro.faults import DROP_DENOM, FaultPlan
 from repro.kernels import engine_step
+from repro.kernels.engine_step.ops import PREF_BLOCK_A, pick_block
 from repro.obs.runreport import span
 from repro.obs.schema import TELE_K, TELE_NSUM, window_len
 
@@ -363,6 +364,22 @@ def topo_lookup(p: SimParams) -> str:
     if not topo_registry.get(p.topology).levels:
         return "none"
     return "select" if p.n_addrs <= _TOPO_SELECT_BANKS else "gather"
+
+
+def bank_tiles(p: SimParams) -> int:
+    """Bank tiles of the engine-step kernel's grid for ``p`` (``a //
+    block_a``), 0 on the XLA scan path, which runs no kernel."""
+    if resolve_backend(p.backend) == "xla_cpu":
+        return 0
+    return p.n_addrs // pick_block(p.n_addrs, PREF_BLOCK_A)
+
+
+def dispatch_args(p: SimParams) -> Dict[str, object]:
+    """The regime a run of ``p`` traces, as the dispatch spans and
+    ``ChunkRecord`` record it: the topology lookup (``topo``), the bank
+    count (``banks``) and the kernel grid (``bank_tiles``)."""
+    return dict(topo=topo_lookup(p), banks=p.n_addrs,
+                bank_tiles=bank_tiles(p))
 
 
 def _resolve(p: SimParams, dyn: Optional[Dict] = None) -> SimpleNamespace:
@@ -1192,7 +1209,7 @@ def execute(p: SimParams, energy_fit=None) -> Dict[str, np.ndarray]:
     dict.  Internal engine entry point: the supported public surface is
     :func:`repro.sync.run`, which wraps this in a typed
     :class:`repro.sync.Result`."""
-    with span("repro.run.dispatch", topo=topo_lookup(p)):
+    with span("repro.run.dispatch", **dispatch_args(p)):
         out = _run(p)
     with span("repro.run.fetch"):
         res = {k: np.asarray(v) for k, v in out.items()}

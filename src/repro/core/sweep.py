@@ -70,7 +70,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.sim import (DYN_FIELDS, _DENSE_BANK_ELTS, SimParams,
-                            derive_metrics, simulate, topo_lookup)
+                            derive_metrics, dispatch_args, simulate)
 from repro.obs.runreport import span
 
 #: fields that must match for configs to share one compilation — the
@@ -355,12 +355,12 @@ def sweep_iter(configs: Sequence[SimParams],
                 else rep
             if sharding is not None:
                 dyn = jax.device_put(dyn, sharding)
-            topo = topo_lookup(crep)
+            regime = dispatch_args(crep)
             cache_before = _sweep_group._cache_size() \
                 if report is not None else 0
             try:
                 with span("repro.sweep.dispatch", report, chunk=ck,
-                          topo=topo) as took:
+                          **regime) as took:
                     out = _sweep_group(crep, dyn, len(padded),
                                        None if sharding is None
                                        else sharding.mesh)
@@ -384,7 +384,7 @@ def sweep_iter(configs: Sequence[SimParams],
                     compile_s=took.seconds, execute_s=0.0,
                     compiled=compiled,
                     devices=len(jax.tree.leaves(out)[0].sharding.device_set),
-                    topo=topo)
+                    **regime)
                 rec = report.chunks[-1]
             pending.append((ck, part, out, rec))
             if len(pending) >= window:

@@ -99,6 +99,8 @@ class ChunkRecord:
     compiled: bool        # this dispatch built a new in-process executable
     devices: int = 1      # devices the chunk's result batch is sharded over
     topo: str = "none"    # topology lookup traced: none | select | gather
+    banks: int = 0        # bank count of the traced program
+    bank_tiles: int = 0   # kernel grid, a // block_a; 0: no kernel ran
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -132,12 +134,14 @@ class RunReport:
     def record_chunk(self, label: str, points: int, batch: int,
                      compile_s: float, execute_s: float,
                      compiled: bool, devices: int = 1,
-                     topo: str = "none") -> None:
+                     topo: str = "none", banks: int = 0,
+                     bank_tiles: int = 0) -> None:
         self.chunks.append(ChunkRecord(label=label, points=points,
                                        batch=batch, compile_s=compile_s,
                                        execute_s=execute_s,
                                        compiled=compiled, devices=devices,
-                                       topo=topo))
+                                       topo=topo, banks=banks,
+                                       bank_tiles=bank_tiles))
 
     # ---- aggregates -----------------------------------------------------
     @property

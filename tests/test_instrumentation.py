@@ -178,6 +178,35 @@ def test_dispatch_spans_and_chunks_name_the_topology_lookup(monkeypatch):
     assert [c["topo"] for c in rep.to_dict()["chunks"]] == forms
 
 
+def test_dispatch_spans_and_chunks_name_the_kernel_grid(monkeypatch):
+    """Every run and chunk says which bank regime it traced: the dispatch
+    spans carry ``banks=`` and ``bank_tiles=`` (the engine-step kernel's
+    grid, 0 on the XLA scan path) and each chunk record keeps both."""
+    from repro.obs import runreport
+    seen = []
+
+    def annotate(name, **args):
+        if name.endswith(".dispatch"):
+            seen.append((name, args["banks"], args["bank_tiles"]))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(runreport, "TraceAnnotation", annotate)
+    base = Spec(protocol="colibri", n_cores=16, costs={"cycles": 100})
+    xla = [base.replace(n_addrs=4, backend="xla_cpu"),
+           base.replace(n_addrs=512, backend="xla_cpu")]
+    tiled = base.replace(n_addrs=512, backend="pallas_interpret")
+    with obs.collect() as rep:
+        assert run(xla[1]).ok and run(tiled).ok
+        assert all(r.ok for r in Study.from_specs(xla + [tiled]).run())
+    regimes = [(4, 0), (512, 0), (512, 2)]
+    assert seen == ([("repro.run.dispatch", 512, 0),
+                     ("repro.run.dispatch", 512, 2)]
+                    + [("repro.sweep.dispatch",) + r for r in regimes])
+    assert [(c.banks, c.bank_tiles) for c in rep.chunks] == regimes
+    assert [(c["banks"], c["bank_tiles"])
+            for c in rep.to_dict()["chunks"]] == regimes
+
+
 def test_failed_chunk_records_an_isolate_span(monkeypatch):
     orig = sweep_mod._sweep_group
 

@@ -7,7 +7,8 @@ engine (``sim.simulate``) and the vmapped sweep runner with the fused
 ``engine_step`` kernel for every registered protocol, at the paper's
 256-core platform and at the shapes that once broke lowering: a
 multi-tile bank grid (1024 banks), the 1024-core ``cluster2`` machine,
-4096 cores, and a sweep batch sharded over four chips.  Each compiled
+that machine at its full 4096 banks, 4096 cores, and a sweep batch
+sharded over four chips.  Each compiled
 program must contain the kernel (``tpu_custom_call``).  Nothing runs, so
 these say nothing about results or speed: ``chip_smoke.py`` checks those
 on the chip.
@@ -93,7 +94,11 @@ def test_engine_lowers_every_protocol(protocol, tpu_params, topo):
     dict(protocol="colibri_hier", n_cores=1024, n_addrs=4,
          topology="cluster2", clusters=4),
     dict(protocol="colibri", n_cores=4096, n_addrs=4),    # 4 core chunks
-], ids=["multi_tile", "cluster2_1024", "colibri_4096"])
+    # the terapool1024_4096banks cell: 16 bank tiles, gather lookup
+    dict(protocol="colibri_hier", n_cores=1024, n_addrs=4096,
+         topology="cluster2", clusters=4, workload="zipf_histogram",
+         zipf_skew=100),
+], ids=["multi_tile", "cluster2_1024", "colibri_4096", "terapool_4096banks"])
 def test_engine_lowers_at_scale(shape, tpu_params, topo):
     from jax.sharding import SingleDeviceSharding
     _assert_kernel(_compile_run(tpu_params(**shape),
