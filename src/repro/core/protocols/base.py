@@ -135,6 +135,36 @@ def put_cols(buf, col, mask, val):
     return jnp.where(hit, val[:, None] if val.ndim else val, buf)
 
 
+# ---- bank-to-core delivery ------------------------------------------------
+# A bank's result of a cycle belongs to one core, and that core targets
+# the bank: a bank serves only a core whose target ``wa`` is that bank, and
+# a core sleeps only in a queue of the bank it asked.  So "bank b sends x to
+# core c" is also "core c reads x from its own bank, if that bank names
+# c".  The engine picks the direction from the static shape
+# (``core.sim.bank_to_core``, handed on as ``Ctx.to_cores``):
+# ``"scatter"`` writes each bank's result onto its core, one index per
+# bank; ``"gather"`` has each core read its own bank, one index per core,
+# and is the cheaper one when there are few cores per bank.  Both give
+# the same bits.
+
+def at_cores(wa, *rows):
+    """The gather form's read: each core's values of the per-bank
+    ``rows`` at its own bank ``wa``, one gather of the rows stacked
+    (int32 or bool rows; each keeps its dtype)."""
+    got = jnp.stack([r.astype(jnp.int32) for r in rows], axis=1)[wa]
+    return tuple(got[:, i].astype(r.dtype) for i, r in enumerate(rows))
+
+
+def fired(form, fire_core, wc):
+    """(n,) bool: the cores their banks fire.  ``fire_core`` is the core
+    each bank fires (``n``: none): per bank on the scatter form, which
+    scatters it onto the cores; on the gather form per core, as read at
+    its own bank (:func:`at_cores`), and a core is fired iff it is named."""
+    if form == "gather":
+        return fire_core == wc
+    return jnp.zeros(wc.shape, bool).at[fire_core].set(True, mode="drop")
+
+
 def onehot_rows(idx, mask, size):
     """``(len(idx), size)`` bool: lane ``i`` selects row ``idx[i]`` of a
     length-``size`` array when ``mask[i]`` (distinct lanes must select
@@ -192,6 +222,9 @@ class Ctx:
     #: cycles between load and store are a per-step property, not the
     #: global ``p.modify``; wake paths must grant with this value.
     mod_dur: jnp.ndarray = None
+    #: how per-bank results reach the core lanes, ``"scatter"`` or
+    #: ``"gather"`` (static; see :func:`at_cores` and :func:`fired`)
+    to_cores: str = "scatter"
 
 
 @dataclasses.dataclass
@@ -317,10 +350,17 @@ class Protocol:
         fire = wake_tmr == 1
         wake_tmr = jnp.maximum(wake_tmr - 1, 0)
         ba = ctx.ba if ctx.ba is not None else jnp.arange(ctx.a)
-        head_core = bank["qbuf"][ba, bank["qhead"]]
         # wake the head core of each firing queue
-        fire_core = jnp.where(fire & (bank["qlen"] > 0), head_core, ctx.n)
-        woken = jnp.zeros((ctx.n,), bool).at[fire_core].set(True, mode="drop")
+        if ctx.to_cores == "gather":
+            # each core reads the head of its own bank's queue
+            head, live = at_cores(ctx.wa, bank["qhead"],
+                                  fire & (bank["qlen"] > 0))
+            fire_core = jnp.where(live, bank["qbuf"][ctx.wa, head], ctx.n)
+        else:
+            head_core = bank["qbuf"][ba, bank["qhead"]]
+            fire_core = jnp.where(fire & (bank["qlen"] > 0), head_core,
+                                  ctx.n)
+        woken = fired(ctx.to_cores, fire_core, ctx.wc)
         cs["st"] = jnp.where(woken, MOD, cs["st"])
         cs["tmr"] = jnp.where(woken, ctx.mod_dur, cs["tmr"])
         bank["wake_tmr"] = wake_tmr

@@ -27,8 +27,9 @@ from repro.core.protocols.base import (MOD, NXT_MOD, NXT_WORK_DONE, OUT_DONE,
                                        OUT_EVICT, OUT_GRANT, OUT_NONE,
                                        OUT_REDELIVER, OUT_SLEEP, RESP, SLEEP,
                                        Contract, FusedOut, Protocol,
-                                       onehot_rows, put_cols, scatter_rows,
-                                       take_cols, take_rows)
+                                       at_cores, fired, onehot_rows,
+                                       put_cols, scatter_rows, take_cols,
+                                       take_rows)
 from repro.core.protocols.registry import register
 
 
@@ -314,15 +315,31 @@ class ColibriHier(Protocol):
         lqbuf, lqhead, lqlen = bank["lqbuf"], bank["lqhead"], bank["lqlen"]
         fire = wake_tmr == 1
         wake_tmr = jnp.maximum(wake_tmr - 1, 0)
-        head_core = lqbuf[wq, lqhead[wq]]
-        valid = fire & (lqlen[wq] > 0)
-        fire_core = jnp.where(valid, head_core, ctx.n)
-        woken = jnp.zeros((ctx.n,), bool).at[fire_core].set(True, mode="drop")
+        gather = ctx.to_cores == "gather"
+        if gather:
+            # queue (b, g) is row b, column g of the (a, G) view: the
+            # woken group's head and length are selects, and each core
+            # reads the head of its own bank's woken queue
+            wg = bank["wake_grp"]
+            lqhead, lqlen = lqhead.reshape(ctx.a, G), lqlen.reshape(ctx.a, G)
+            hd, ln = take_cols(lqhead, wg), take_cols(lqlen, wg)
+            valid = fire & (ln > 0)
+            q, h, live = at_cores(ctx.wa, wq, hd, valid)
+            fire_core = jnp.where(live, lqbuf[q, h], ctx.n)
+        else:
+            head_core = lqbuf[wq, lqhead[wq]]
+            valid = fire & (lqlen[wq] > 0)
+            fire_core = jnp.where(valid, head_core, ctx.n)
+        woken = fired(ctx.to_cores, fire_core, ctx.wc)
         cs["st"] = jnp.where(woken, MOD, cs["st"])
         cs["tmr"] = jnp.where(woken, ctx.mod_dur, cs["tmr"])
         # pop the woken head: it is now the address's active holder
-        oob = jnp.where(valid, wq, ctx.a * G)
-        lqhead = (lqhead.at[oob].add(1, mode="drop")) % cap_l
-        lqlen = lqlen.at[oob].add(-1, mode="drop")
+        if gather:
+            lqhead = put_cols(lqhead, wg, valid, (hd + 1) % cap_l).reshape(-1)
+            lqlen = put_cols(lqlen, wg, valid, ln - 1).reshape(-1)
+        else:
+            oob = jnp.where(valid, wq, ctx.a * G)
+            lqhead = (lqhead.at[oob].add(1, mode="drop")) % cap_l
+            lqlen = lqlen.at[oob].add(-1, mode="drop")
         bank.update(wake_tmr=wake_tmr, lqhead=lqhead, lqlen=lqlen)
         return cs, bank, (wake_tmr == 1).sum()

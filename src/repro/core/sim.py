@@ -59,7 +59,8 @@ from repro.core.protocols.base import (BACKOFF, BARWAIT, MOD, NXT_BACKOFF,
                                        NXT_MOD, NXT_WORK_DONE, OUT_DONE,
                                        OUT_EVICT, OUT_FAIL, OUT_GRANT,
                                        OUT_NONE, OUT_SLEEP, P_ACQ, P_REL,
-                                       REQ, RESP, SLEEP, WORK)
+                                       REQ, RESP, SLEEP, WORK, at_cores,
+                                       fired)
 from repro.core.workloads.base import (ADDR_FIXED, ADDR_ZIPF, K_BARRIER,
                                        zipf_index)
 from repro.faults import DROP_DENOM, FaultPlan
@@ -157,6 +158,17 @@ _DENSE_BATCH_ELTS = 131072
 #: 16% of the whole cycle at a = 128; at a = 256 it still won 14% per
 #: cycle but compiled 9 s slower (18 s against 9), at 1024 it won 3%.
 _TOPO_SELECT_BANKS = 128
+
+#: cores per bank down to which per-bank results (arbitration outcomes,
+#: queue wake-ups) reach the core lanes by a scatter from the banks; with
+#: fewer cores per bank each core gathers from its own bank instead.  The
+#: scatter form pays one index per bank in each of several ops
+#: (colibri_hier: six in sim.wake, five in sim.arbitrate), the gather
+#: form one index per core in three.  Measured on a TPU v5e at 1024 cores
+#: (zipf_hist's machine), µs of wall per cycle, scatter / gather: a = 256
+#: 68.0 / 71.2, a = 1024 160.9 / 113.6, a = 2048 291.2 / 179.5, a = 4096
+#: 545.8 / 308.1; the two meet near 300 banks.
+_SCATTER_CORES_PER_BANK = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -366,6 +378,19 @@ def topo_lookup(p: SimParams) -> str:
     return "select" if p.n_addrs <= _TOPO_SELECT_BANKS else "gather"
 
 
+def bank_to_core(p: SimParams) -> str:
+    """How per-bank results (arbitration outcomes, queue wake-ups) reach
+    the core lanes: ``"scatter"``, each bank writing its core (one index
+    per bank), while there are at least ``_SCATTER_CORES_PER_BANK`` cores
+    per bank, else ``"gather"``, each core reading its own bank (one
+    index per core).  Results are bit-identical.  Handed to the protocols
+    as ``Ctx.to_cores``; the dispatch spans and ``ChunkRecord.to_cores``
+    record it."""
+    if p.n_cores >= _SCATTER_CORES_PER_BANK * p.n_addrs:
+        return "scatter"
+    return "gather"
+
+
 def bank_tiles(p: SimParams) -> int:
     """Bank tiles of the engine-step kernel's grid for ``p`` (``a //
     block_a``), 0 on the XLA scan path, which runs no kernel."""
@@ -377,9 +402,10 @@ def bank_tiles(p: SimParams) -> int:
 def dispatch_args(p: SimParams) -> Dict[str, object]:
     """The regime a run of ``p`` traces, as the dispatch spans and
     ``ChunkRecord`` record it: the topology lookup (``topo``), the bank
-    count (``banks``) and the kernel grid (``bank_tiles``)."""
+    count (``banks``), the kernel grid (``bank_tiles``) and the direction
+    of the bank-to-core delivery (``to_cores``)."""
     return dict(topo=topo_lookup(p), banks=p.n_addrs,
-                bank_tiles=bank_tiles(p))
+                bank_tiles=bank_tiles(p), to_cores=bank_to_core(p))
 
 
 def _resolve(p: SimParams, dyn: Optional[Dict] = None) -> SimpleNamespace:
@@ -425,6 +451,7 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
     tt = topo.tables(p, n, a)
     use_topo = not tt.is_flat
     lookup = topo_lookup(p)
+    to_cores = bank_to_core(p)
 
     state = dict(
         st=jnp.full((n,), WORK, jnp.int32),
@@ -815,11 +842,12 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
             if use_pallas:
                 # fused engine-step kernel (repro.kernels.engine_step):
                 # arbitration + protocol bank update + latency histogram in
-                # one tiled pass over (a, n); the engine scatters the
-                # per-bank outcome codes back to the winning cores below —
-                # exactly the (st, tmr, nxt) writes on_access performs via
-                # masked wheres, so the two paths stay bit-identical
-                # (tests/test_engine_backend.py).
+                # one tiled pass over (a, n); the engine hands the per-bank
+                # outcome codes back to the winning cores below (scattered
+                # from the banks, or gathered by each core at its own bank:
+                # ``bank_to_core``) — exactly the (st, tmr, nxt) writes
+                # on_access performs via masked wheres, so the two paths
+                # stay bit-identical (tests/test_engine_backend.py).
                 fs = engine_step.fused_step(
                     proto, p, dict(s["bank"]),
                     cand_cyc=jnp.where(arrived, arr_cyc, _BIG),
@@ -829,8 +857,18 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                     n=n, a=a, q_cap=q_cap, cycles=p.cycles,
                     interpret=pl_interpret)
                 valid_b, win_core, kind = fs["valid"], fs["win"], fs["kind"]
-                winner = jnp.zeros((n,), bool).at[
-                    jnp.where(valid_b, win_core, n)].set(True, mode="drop")
+                xsets = [fs["xset"][f] for f in proto.fused_xset_fields]
+                if to_cores == "gather":
+                    # each core reads its own bank's outcome row: one
+                    # gather of n indices, and the writes below are selects
+                    fire_c, kind_c, tmr_c, *xs_c = at_cores(
+                        addr, jnp.where(valid_b, win_core, n), kind,
+                        fs["tmr"], *[r for vm in xsets for r in vm])
+                    xsets = list(zip(xs_c[0::2], xs_c[1::2]))
+                    winner = fired(to_cores, fire_c, iota)
+                else:
+                    winner = jnp.zeros((n,), bool).at[
+                        jnp.where(valid_b, win_core, n)].set(True, mode="drop")
                 parked = parked & ~winner                    # served
                 arr_cyc = jnp.where(winner, -1, arr_cyc)
                 wcs = jnp.minimum(win_core, n - 1)           # gather-safe
@@ -838,18 +876,31 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                 rel_b = valid_b & (phase[wcs] == P_REL)
                 is_acq = winner & (phase == P_ACQ)
                 is_rel = winner & (phase == P_REL)
-                resp_k = ((kind == OUT_GRANT) | (kind == OUT_DONE)
-                          | (kind == OUT_FAIL))
-                rw = jnp.where(resp_k, win_core, n)
-                st = st.at[rw].set(RESP, mode="drop")
-                st = st.at[jnp.where(kind == OUT_SLEEP, win_core, n)].set(
-                    SLEEP, mode="drop")
-                tmr = tmr.at[rw].set(fs["tmr"], mode="drop")
-                nxt_code = jnp.where(
-                    kind == OUT_GRANT, NXT_MOD,
-                    jnp.where(kind == OUT_DONE, NXT_WORK_DONE,
-                              NXT_BACKOFF)).astype(jnp.int32)
-                nxt = s["nxt"].at[rw].set(nxt_code, mode="drop")
+                if to_cores == "gather":
+                    resp_c = winner & ((kind_c == OUT_GRANT)
+                                       | (kind_c == OUT_DONE)
+                                       | (kind_c == OUT_FAIL))
+                    st = jnp.where(resp_c, RESP, st)
+                    st = jnp.where(winner & (kind_c == OUT_SLEEP), SLEEP, st)
+                    tmr = jnp.where(resp_c, tmr_c, tmr)
+                    nxt_c = jnp.where(
+                        kind_c == OUT_GRANT, NXT_MOD,
+                        jnp.where(kind_c == OUT_DONE, NXT_WORK_DONE,
+                                  NXT_BACKOFF)).astype(jnp.int32)
+                    nxt = jnp.where(resp_c, nxt_c, s["nxt"])
+                else:
+                    resp_k = ((kind == OUT_GRANT) | (kind == OUT_DONE)
+                              | (kind == OUT_FAIL))
+                    rw = jnp.where(resp_k, win_core, n)
+                    st = st.at[rw].set(RESP, mode="drop")
+                    st = st.at[jnp.where(kind == OUT_SLEEP, win_core, n)].set(
+                        SLEEP, mode="drop")
+                    tmr = tmr.at[rw].set(fs["tmr"], mode="drop")
+                    nxt_code = jnp.where(
+                        kind == OUT_GRANT, NXT_MOD,
+                        jnp.where(kind == OUT_DONE, NXT_WORK_DONE,
+                                  NXT_BACKOFF)).astype(jnp.int32)
+                    nxt = s["nxt"].at[rw].set(nxt_code, mode="drop")
                 cs = dict(st=st, tmr=tmr, nxt=nxt,
                           polls=s["polls"] + fs["polls"],
                           msgs=(s["msgs"] + 2 * winner.sum() + bar_msgs
@@ -857,17 +908,19 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                           **{k: s["xc"][k] for k in xc_keys})
                 # protocol per-core writes (e.g. the ticket lock's drawn
                 # ticket) come back as (values, mask) pairs
-                for f in proto.fused_xset_fields:
-                    val, msk = fs["xset"][f]
-                    cs[f] = cs[f].at[jnp.where(msk, win_core, n)].set(
-                        val, mode="drop")
+                for f, (val, msk) in zip(proto.fused_xset_fields, xsets):
+                    if to_cores == "gather":
+                        cs[f] = jnp.where(winner & msk, val, cs[f])
+                    else:
+                        cs[f] = cs[f].at[jnp.where(msk, win_core, n)].set(
+                            val, mode="drop")
                 bank = fs["bank"]
                 ctx = proto_registry.Ctx(p=rp, n=n, a=a, q_cap=q_cap,
                                          is_acq=is_acq, is_rel=is_rel,
                                          wa=addr, wc=iota, ba=ba,
                                          win_core=win_core, acq_b=acq_b,
                                          rel_b=rel_b,
-                                         mod_dur=mod_dur)
+                                         mod_dur=mod_dur, to_cores=to_cores)
             else:
                 if key_fits_int32:
                     # fused lexicographic key, one segment-min (the common
@@ -908,7 +961,7 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
                                          wa=addr, wc=iota, ba=ba,
                                          win_core=win_core, acq_b=acq_b,
                                          rel_b=rel_b,
-                                         mod_dur=mod_dur)
+                                         mod_dur=mod_dur, to_cores=to_cores)
                 cs, bank = proto.on_access(ctx, cs, dict(s["bank"]))
             bank_ops = s["bank_ops"] + winner.sum()
 

@@ -101,6 +101,7 @@ class ChunkRecord:
     topo: str = "none"    # topology lookup traced: none | select | gather
     banks: int = 0        # bank count of the traced program
     bank_tiles: int = 0   # kernel grid, a // block_a; 0: no kernel ran
+    to_cores: str = "scatter"  # bank-to-core delivery: scatter | gather
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -135,13 +136,15 @@ class RunReport:
                      compile_s: float, execute_s: float,
                      compiled: bool, devices: int = 1,
                      topo: str = "none", banks: int = 0,
-                     bank_tiles: int = 0) -> None:
+                     bank_tiles: int = 0,
+                     to_cores: str = "scatter") -> None:
         self.chunks.append(ChunkRecord(label=label, points=points,
                                        batch=batch, compile_s=compile_s,
                                        execute_s=execute_s,
                                        compiled=compiled, devices=devices,
                                        topo=topo, banks=banks,
-                                       bank_tiles=bank_tiles))
+                                       bank_tiles=bank_tiles,
+                                       to_cores=to_cores))
 
     # ---- aggregates -----------------------------------------------------
     @property

@@ -77,7 +77,8 @@ def test_the_regime_is_the_full_bank_one(fields):
     p = Spec(**fields, backend="pallas_interpret").to_params()
     assert p.n_addrs == BANKS > sim._TOPO_SELECT_BANKS
     assert sim.dispatch_args(p) == dict(
-        topo="gather", banks=BANKS, bank_tiles=BANKS // PREF_BLOCK_A)
+        topo="gather", banks=BANKS, bank_tiles=BANKS // PREF_BLOCK_A,
+        to_cores="gather")
     assert BANKS // PREF_BLOCK_A == 2
 
 

@@ -180,14 +180,17 @@ def test_dispatch_spans_and_chunks_name_the_topology_lookup(monkeypatch):
 
 def test_dispatch_spans_and_chunks_name_the_kernel_grid(monkeypatch):
     """Every run and chunk says which bank regime it traced: the dispatch
-    spans carry ``banks=`` and ``bank_tiles=`` (the engine-step kernel's
-    grid, 0 on the XLA scan path) and each chunk record keeps both."""
+    spans carry ``banks=``, ``bank_tiles=`` (the engine-step kernel's
+    grid, 0 on the XLA scan path) and ``to_cores=`` (the bank-to-core
+    delivery, ``gather`` with few cores per bank), and each chunk
+    record keeps all three."""
     from repro.obs import runreport
     seen = []
 
     def annotate(name, **args):
         if name.endswith(".dispatch"):
-            seen.append((name, args["banks"], args["bank_tiles"]))
+            seen.append((name, args["banks"], args["bank_tiles"],
+                         args["to_cores"]))
         return contextlib.nullcontext()
 
     monkeypatch.setattr(runreport, "TraceAnnotation", annotate)
@@ -198,12 +201,13 @@ def test_dispatch_spans_and_chunks_name_the_kernel_grid(monkeypatch):
     with obs.collect() as rep:
         assert run(xla[1]).ok and run(tiled).ok
         assert all(r.ok for r in Study.from_specs(xla + [tiled]).run())
-    regimes = [(4, 0), (512, 0), (512, 2)]
-    assert seen == ([("repro.run.dispatch", 512, 0),
-                     ("repro.run.dispatch", 512, 2)]
+    regimes = [(4, 0, "scatter"), (512, 0, "gather"), (512, 2, "gather")]
+    assert seen == ([("repro.run.dispatch", 512, 0, "gather"),
+                     ("repro.run.dispatch", 512, 2, "gather")]
                     + [("repro.sweep.dispatch",) + r for r in regimes])
-    assert [(c.banks, c.bank_tiles) for c in rep.chunks] == regimes
-    assert [(c["banks"], c["bank_tiles"])
+    assert [(c.banks, c.bank_tiles, c.to_cores)
+            for c in rep.chunks] == regimes
+    assert [(c["banks"], c["bank_tiles"], c["to_cores"])
             for c in rep.to_dict()["chunks"]] == regimes
 
 
