@@ -1,8 +1,7 @@
 """Benchmark driver — one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows plus a headline summary that
-EXPERIMENTS.md quotes. Roofline/dry-run analysis lives in
-``benchmarks/roofline.py`` (reads reports/dryrun/*.json).
+EXPERIMENTS.md quotes.
 
 ``--list`` prints the available benchmark names; ``--only <name>`` runs
 one benchmark (an exact name match wins, otherwise substring match);

@@ -1,7 +1,6 @@
-"""Quickstart: train a reduced smollm-135m on CPU for a few steps,
-reproduce the paper's headline result (Fig. 3 ratios) through the
-public ``repro.sync`` API, then run one concurrent-algorithm workload
-from the workload registry through every class of protocol.
+"""Quickstart: reproduce the paper's headline result (Fig. 3 ratios)
+through the public ``repro.sync`` API, then run one concurrent-algorithm
+workload from the workload registry through every class of protocol.
 
     PYTHONPATH=src python examples/quickstart.py
 
@@ -11,22 +10,13 @@ don't.
 """
 import os
 
-from repro.configs import get_config
-from repro.configs.base import ShapeSpec
-from repro.launch.train import TrainRun, run_training
 from repro.sync import Spec, Study, run, scenario, workloads
 
 QUICK = bool(int(os.environ.get("REPRO_BENCH_QUICK", "0")))
 
 
 def main():
-    print("=== 1. train a reduced smollm-135m (CPU) ===")
-    cfg = get_config("smollm-135m-smoke")
-    out = run_training(TrainRun(cfg=cfg, shape=ShapeSpec("t", 128, 4, "train"),
-                                steps=20, log_every=5))
-    print(f"final loss: {out['loss']:.4f}\n")
-
-    print("=== 2. paper headline: Colibri vs LRSC (Fig. 3) ===")
+    print("=== 1. paper headline: Colibri vs LRSC (Fig. 3) ===")
     study = Study(Spec(cycles=2_000 if QUICK else 20_000)) \
         .grid(protocol=("colibri", "lrsc"), n_addrs=(1, 256))
     t = {(r.spec.protocol.name, r.spec.topology.n_addrs): r.throughput
@@ -36,7 +26,7 @@ def main():
     print(f"high contention: colibri/lrsc = {hi:.2f}x (paper: 6.5x)")
     print(f"low contention:  colibri/lrsc = {lo:.2f}x (paper: 1.13x)\n")
 
-    print("=== 3. workload registry: a concurrent queue, three protocols ===")
+    print("=== 2. workload registry: a concurrent queue, three protocols ===")
     print(f"registered workloads: {', '.join(workloads())}")
     for proto in ("colibri", "lrsc", "amo_lock"):
         r = run(Spec(protocol=proto, workload="ms_queue", n_cores=64,

@@ -11,7 +11,6 @@ benchmark's plain reference (``bench/reference.py``, loaded from its
 file) on every statistic, while the reference with first-come bank
 service broken (the cell's ``fifo`` control) does not.
 """
-import importlib.util
 import json
 import os
 
@@ -27,15 +26,9 @@ CONFIG = os.path.join(BENCH, "configs", "terapool1024_4096banks.json")
 N_CORES, BANKS, CYCLES, SEED = 64, 512, 400, 2**31 - 12345
 
 
-def _bench_module(name):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + name, os.path.join(BENCH, name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-check = _bench_module("check")
+@pytest.fixture(scope="module")
+def check(bench_module):
+    return bench_module("check")
 
 
 @pytest.fixture(scope="module")
@@ -59,9 +52,9 @@ def results(fields):
 
 
 @pytest.fixture(scope="module")
-def reference(config, fields):
+def reference(config, fields, bench_module):
     """The plain reference's statistics, and its ``fifo`` control's."""
-    ref = _bench_module("reference")
+    ref = bench_module("reference")
     point = {k: fields[k] for k in (
         "protocol", "workload", "topology", "clusters", "n_groups",
         "n_cores", "cycles", "q_slots") + ref.DYN}
@@ -82,13 +75,13 @@ def test_the_regime_is_the_full_bank_one(fields):
     assert BANKS // PREF_BLOCK_A == 2
 
 
-def test_backends_are_bit_identical(results):
+def test_backends_are_bit_identical(results, check):
     assert check.fields_differ(results["xla_cpu"],
                                results["pallas_interpret"]) == []
 
 
 @pytest.mark.parametrize("backend", ["xla_cpu", "pallas_interpret"])
-def test_backend_equals_the_reference(backend, results, reference):
+def test_backend_equals_the_reference(backend, results, reference, check):
     got = results[backend]
     assert check.fields_differ(got, reference[None]) == []
     # the regime is the contended one: many banks busy, cores asleep in
@@ -98,5 +91,5 @@ def test_backend_equals_the_reference(backend, results, reference):
     assert got["addr_ops"].sum() == got["ops"].sum() > 0
 
 
-def test_fifo_control_disagrees(results, reference):
+def test_fifo_control_disagrees(results, reference, check):
     assert check.fields_differ(results["xla_cpu"], reference["fifo"])
