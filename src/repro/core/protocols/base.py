@@ -135,6 +135,37 @@ def put_cols(buf, col, mask, val):
     return jnp.where(hit, val[:, None] if val.ndim else val, buf)
 
 
+# The same two for state stored with banks on the last (lane) axis, an
+# ``(r, a)`` buffer whose column ``b`` is bank ``b``'s ``r`` entries: each
+# lane reads or writes its own column at its own row index.
+
+def take_lanes(buf, row):
+    """``buf[row, arange(c)]`` for an ``(r, c)`` buffer: each column's
+    element at its own row index."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == row[None, :]
+    if buf.dtype == jnp.bool_:
+        return jnp.sum(jnp.where(hit & buf, 1, 0), axis=0) > 0
+    return jnp.sum(jnp.where(hit, buf, 0), axis=0).astype(buf.dtype)
+
+
+def put_lanes(buf, row, mask, val):
+    """``buf.at[row, arange(c)].set(val)`` on the columns where ``mask``;
+    ``val`` as for :func:`put_cols`."""
+    row = jnp.where(mask, row, -1)
+    if buf.dtype != jnp.bool_ and jnp.ndim(val):
+        # row and value reach the lanes as one (2, c) array: Mosaic lays
+        # them out together, where a value broadcast on its own added
+        # ~3.5 s to the kernel's compile at a 256-bank tile (AOT, v5e)
+        rv = jnp.stack([row, jnp.asarray(val, jnp.int32)])
+        row, val = rv[0:1], rv[1:2].astype(buf.dtype)
+    else:
+        row = row[None, :]
+    hit = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 0) == row
+    if buf.dtype == jnp.bool_:
+        return (buf | hit) if val else (buf & ~hit)
+    return jnp.where(hit, jnp.asarray(val, buf.dtype), buf)
+
+
 # ---- bank-to-core delivery ------------------------------------------------
 # A bank's result of a cycle belongs to one core, and that core targets
 # the bank: a bank serves only a core whose target ``wa`` is that bank, and
@@ -240,8 +271,9 @@ class FusedCtx:
     and scattered by the engine.  A conforming ``fused_access``:
 
     * reads/writes bank state arrays sliced to this block (every bank
-      array's leading dim is ``m * a`` for some per-protocol ``m``, so
-      blocks slice cleanly);
+      array's leading dim is ``m * a`` for some per-protocol ``m``, or,
+      where the protocol sets :attr:`Protocol.bank_last`, its last dim
+      is ``a``, so blocks slice cleanly);
     * indexes banks with a **local** iota (``jnp.arange(a)``), never a
       global bank id;
     * touches per-core state only through ``core`` (values the engine
@@ -299,6 +331,11 @@ class Protocol:
     #: per-core state fields ``fused_access`` may write via
     #: ``FusedOut.xset`` (static: sizes the kernel's output pytree)
     fused_xset_fields: Tuple[str, ...] = ()
+    #: bank-state layout: ``False``, every bank array's leading dim is
+    #: ``m * a``; ``True``, every bank array is ``(a,)`` or ``(r, a)``,
+    #: the bank on the last (lane) axis (the engine-step kernel tiles it
+    #: ``(r, block_a)``)
+    bank_last: bool = False
 
     # ---- static sizing ----
     def q_cap(self, p, n: int) -> int:
@@ -316,6 +353,12 @@ class Protocol:
         FIFO queue's ``qlen``; hierarchical protocols override to sum
         their per-bank lanes."""
         return bank.get("qlen")
+
+    def result_bank(self, bank: Dict) -> Dict:
+        """The final bank state as ``Result.stats`` carries it.  Default:
+        as stored; a protocol that stores state in a layout made for the
+        kernel (:attr:`bank_last`) maps it back to its documented one."""
+        return bank
 
     def init_core_state(self, p, n: int) -> Dict:
         return {}

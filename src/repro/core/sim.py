@@ -1213,7 +1213,7 @@ def simulate(p: SimParams, dyn: Optional[Dict] = None, batch: int = 1
     # flatten protocol state into the result dict (names never collide
     # with engine keys)
     flat = {k: v for k, v in final.items() if k not in ("bank", "xc")}
-    flat.update(final["bank"])
+    flat.update(proto.result_bank(final["bank"]))
     flat.update(final["xc"])
     if use_faults:
         # dead-at-horizon core mask for the survivor metrics (holder

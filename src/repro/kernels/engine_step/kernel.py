@@ -15,10 +15,15 @@ cycle (see ``ref.py`` for the op-by-op oracle):
 Grid: ``(a // block_a,)`` bank tiles; the core dimension is swept by an
 in-kernel ``fori_loop`` over ``n // block_n`` chunks, so no grid cell
 ever depends on another (safe on parallel GPU grids, trivially correct
-under ``interpret=True`` on CPU).  Bank-state arrays follow the layout
-rule that their leading dim is ``m * a`` for a per-protocol ``m`` (flat
-Colibri queues: m=1; hierarchical local queues: m=n_groups), so every
-bank array blocks cleanly to ``(m * block_a, ...)`` at tile ``at``.
+under ``interpret=True`` on CPU).  Bank-state arrays follow one of two
+layout rules, which the protocol declares (``Protocol.bank_last``): by
+default their leading dim is ``m * a`` for a per-protocol ``m`` (flat
+Colibri queues: m=1), so every bank array blocks to ``(m * block_a,
+...)`` at tile ``at``; with ``bank_last`` every bank array is ``(r, a)``
+with the bank on the last (lane) axis (the hierarchical local queues:
+``r`` = n_groups, or n_groups × the local queue's slots), so it blocks
+to ``(r, block_a)`` at ``(0, at)``.  1-D bank arrays travel as ``(1,
+m * a)`` rows and block on the last axis under either rule.
 
 Per-tile partial outputs (histogram rows, [polls, msgs, lat_max] stat
 rows) are reduced OUTSIDE the kernel — cross-tile accumulation through a
@@ -189,8 +194,8 @@ def fused_step_call(proto, p, bank, *, cand_cyc, rot, addr, phase,
         return pl.BlockSpec((1, w), lambda at: (0, 0))
 
     def _banked(shape):                      # bank dim is m*a -> m*block_a
-        if shape[0] == 1:                    # a (1, m*a) row
-            return pl.BlockSpec((1, shape[1] // a * block_a),
+        if proto.bank_last or shape[0] == 1:  # (r, a), or a (1, m*a) row
+            return pl.BlockSpec((shape[0], shape[1] // a * block_a),
                                 lambda at: (0, at))
         rest = tuple(shape[1:])
         return pl.BlockSpec((shape[0] // a * block_a,) + rest,

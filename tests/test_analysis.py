@@ -179,10 +179,12 @@ def test_fail_requires_full_rule():
 # ---------------------------------------------------------------------------
 
 class _WakeGrpAliasing(ColibriHier):
-    """The PR 6 bug, verbatim: ``on_wake`` consumes ``wake_grp`` as a
-    flat local-queue id without rebasing by ``bank * G``, so any wake on
-    a bank other than bank 0 pops (and wakes from) ANOTHER bank's local
-    queue."""
+    """The old ``wake_grp`` aliasing bug: ``on_wake`` consumes it as a flat
+    local-queue id ``bank * G + group`` without rebasing it by the bank,
+    so a wake on any bank other than bank 0 pops (and wakes from)
+    ANOTHER bank's local queue.  On the ``(G, a)`` layout flat id ``f``
+    is queue (bank ``f // G``, group ``f % G``): column ``f // G``, row
+    ``f % G``."""
     name = "mutant_wake_grp_alias"
 
     def on_wake(self, ctx, cs, bank):
@@ -190,19 +192,20 @@ class _WakeGrpAliasing(ColibriHier):
         G, _, cap_l = self._geom(ctx.p, ctx.n)
         wake_tmr = bank["wake_tmr"]
         wq = bank["wake_grp"]                # BUG: missing ba * G rebase
+        g, b = wq % G, wq // G               # the queue flat id wq names
         lqbuf, lqhead, lqlen = bank["lqbuf"], bank["lqhead"], bank["lqlen"]
         fire = wake_tmr == 1
         wake_tmr = jnp.maximum(wake_tmr - 1, 0)
-        head_core = lqbuf[wq, lqhead[wq]]
-        valid = fire & (lqlen[wq] > 0)
+        head_core = lqbuf[g * cap_l + lqhead[g, b], b]
+        valid = fire & (lqlen[g, b] > 0)
         fire_core = jnp.where(valid, head_core, ctx.n)
         woken = jnp.zeros((ctx.n,), bool).at[fire_core].set(True,
                                                             mode="drop")
         cs["st"] = jnp.where(woken, MOD, cs["st"])
         cs["tmr"] = jnp.where(woken, ctx.mod_dur, cs["tmr"])
-        oob = jnp.where(valid, wq, ctx.a * G)
-        lqhead = (lqhead.at[oob].add(1, mode="drop")) % cap_l
-        lqlen = lqlen.at[oob].add(-1, mode="drop")
+        oob = jnp.where(valid, g, G)
+        lqhead = (lqhead.at[oob, b].add(1, mode="drop")) % cap_l
+        lqlen = lqlen.at[oob, b].add(-1, mode="drop")
         bank.update(wake_tmr=wake_tmr, lqhead=lqhead, lqlen=lqlen)
         return cs, bank, (wake_tmr == 1).sum()
 
