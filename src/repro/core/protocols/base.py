@@ -342,6 +342,11 @@ class Protocol:
         """Queue slots per bank (static). Default: one per core."""
         return n
 
+    def queue_slots(self, p, n: int) -> int:
+        """Queue slots per bank row the bank state carries, 0 for a
+        queueless protocol; the dispatch spans record it as ``q_cap``."""
+        return self.q_cap(p, n) if self.uses_queue else 0
+
     # ---- state ----
     def init_bank_state(self, p, a: int, n: int, q_cap: int) -> Dict:
         return {}

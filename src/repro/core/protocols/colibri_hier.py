@@ -54,6 +54,9 @@ class ColibriHier(Protocol):
         cap_l = max(gsz, n - (g - 1) * gsz)  # last group may be larger
         return g, gsz, cap_l
 
+    def queue_slots(self, p, n):
+        return self._geom(p, n)[2]           # a group-local queue's slots
+
     def init_bank_state(self, p, a, n, q_cap):
         # banks on the last (lane) axis, the group (and the local queue's
         # slot) on the leading one: a bank lane reads and writes its own

@@ -402,10 +402,15 @@ def bank_tiles(p: SimParams) -> int:
 def dispatch_args(p: SimParams) -> Dict[str, object]:
     """The regime a run of ``p`` traces, as the dispatch spans and
     ``ChunkRecord`` record it: the topology lookup (``topo``), the bank
-    count (``banks``), the kernel grid (``bank_tiles``) and the direction
-    of the bank-to-core delivery (``to_cores``)."""
+    count (``banks``), the kernel grid (``bank_tiles``), the direction
+    of the bank-to-core delivery (``to_cores``), the queue slots per bank
+    row of the protocol's state (``q_cap``, 0 without a queue) and its
+    layout (``bank_last``: 1 with the bank on the last axis)."""
+    proto = proto_registry.get(p.protocol)
     return dict(topo=topo_lookup(p), banks=p.n_addrs,
-                bank_tiles=bank_tiles(p), to_cores=bank_to_core(p))
+                bank_tiles=bank_tiles(p), to_cores=bank_to_core(p),
+                q_cap=proto.queue_slots(p, p.n_cores),
+                bank_last=int(proto.bank_last))
 
 
 def _resolve(p: SimParams, dyn: Optional[Dict] = None) -> SimpleNamespace:
@@ -1257,6 +1262,16 @@ def derive_metrics(res: Dict[str, np.ndarray], n_workers: int, cycles: int,
     return metrics_mod.attach(res, n_workers, cycles, fit=energy_fit)
 
 
+def run_shares(p: SimParams, res: Dict[str, np.ndarray]) -> Dict[str, float]:
+    """What the ``repro.run.metrics`` span records of a fetched run: the
+    share of core-cycles spent asleep (``sleep_share``) and the network
+    messages per completed atomic (``msgs_per_op``, 0 without one)."""
+    ops = float(res["ops"].sum())
+    return dict(
+        sleep_share=float(res["sleep_cyc"]) / (p.n_cores * p.cycles),
+        msgs_per_op=float(res["msgs"]) / ops if ops else 0.0)
+
+
 def execute(p: SimParams, energy_fit=None) -> Dict[str, np.ndarray]:
     """Run one configuration and return the raw metric-annotated result
     dict.  Internal engine entry point: the supported public surface is
@@ -1266,7 +1281,7 @@ def execute(p: SimParams, energy_fit=None) -> Dict[str, np.ndarray]:
         out = _run(p)
     with span("repro.run.fetch"):
         res = {k: np.asarray(v) for k, v in out.items()}
-    with span("repro.run.metrics"):
+    with span("repro.run.metrics", **run_shares(p, res)):
         return derive_metrics(res, min(p.n_workers, p.n_cores), p.cycles,
                               energy_fit=energy_fit)
 

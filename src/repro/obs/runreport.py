@@ -102,6 +102,8 @@ class ChunkRecord:
     banks: int = 0        # bank count of the traced program
     bank_tiles: int = 0   # kernel grid, a // block_a; 0: no kernel ran
     to_cores: str = "scatter"  # bank-to-core delivery: scatter | gather
+    q_cap: int = 0        # queue slots per bank row; 0: no queue
+    bank_last: int = 0    # 1: bank state stored with banks on the last axis
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -137,14 +139,16 @@ class RunReport:
                      compiled: bool, devices: int = 1,
                      topo: str = "none", banks: int = 0,
                      bank_tiles: int = 0,
-                     to_cores: str = "scatter") -> None:
+                     to_cores: str = "scatter", q_cap: int = 0,
+                     bank_last: int = 0) -> None:
         self.chunks.append(ChunkRecord(label=label, points=points,
                                        batch=batch, compile_s=compile_s,
                                        execute_s=execute_s,
                                        compiled=compiled, devices=devices,
                                        topo=topo, banks=banks,
                                        bank_tiles=bank_tiles,
-                                       to_cores=to_cores))
+                                       to_cores=to_cores, q_cap=q_cap,
+                                       bank_last=bank_last))
 
     # ---- aggregates -----------------------------------------------------
     @property

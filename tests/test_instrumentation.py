@@ -211,6 +211,55 @@ def test_dispatch_spans_and_chunks_name_the_kernel_grid(monkeypatch):
             for c in rep.to_dict()["chunks"]] == regimes
 
 
+@pytest.mark.parametrize("protocol, fields, q_cap, bank_last", [
+    ("colibri", {}, 16, 0),                  # the queue never fills: n
+    ("lrscwait", {"q_slots": 4}, 4, 0),      # bounded, q < n
+    ("colibri_hier", {"topology": "cluster2", "clusters": 4,
+                      "n_groups": 4}, 4, 1),  # a group-local queue
+    ("amo_lock", {}, 0, 0),                  # no queue
+])
+def test_dispatch_args_name_the_queue_geometry(protocol, fields, q_cap,
+                                               bank_last):
+    """``q_cap`` is the queue slots per bank row of the protocol's bank
+    state, ``bank_last`` its layout: two runs at one bank count with
+    different queue geometry trace different kernels."""
+    p = sim.SimParams(protocol=protocol, n_cores=16, n_addrs=4, cycles=100,
+                      backend="xla_cpu", **fields)
+    args = sim.dispatch_args(p)
+    assert (args["q_cap"], args["bank_last"]) == (q_cap, bank_last)
+
+
+def test_run_spans_carry_the_queue_geometry_and_shares(monkeypatch):
+    """The run's dispatch span and the Study's chunk records carry the
+    queue geometry; the run's metrics span carries the share of
+    core-cycles asleep and the messages per completed atomic, read from
+    the fetched statistics, which gain no key."""
+    from repro.obs import runreport
+    seen = {}
+
+    def annotate(name, **args):
+        seen[name] = args
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(runreport, "TraceAnnotation", annotate)
+    with obs.collect() as rep:
+        r = run(Spec(**GOLDEN_POINT))
+        bounded = Spec(protocol={"name": "lrscwait", "q_slots": 4},
+                       n_cores=16, n_addrs=2, costs={"cycles": 100})
+        assert all(x.ok for x in Study.from_specs([bounded]).run())
+    assert r.ok
+    assert {k: seen["repro.run.dispatch"][k]
+            for k in ("q_cap", "bank_last")} == dict(q_cap=64, bank_last=0)
+    n, cycles = GOLDEN_POINT["n_cores"], GOLDEN_POINT["cycles"]
+    assert seen["repro.run.metrics"] == pytest.approx(dict(
+        sleep_share=GOLDEN_SLEEP / (n * cycles),
+        msgs_per_op=GOLDEN_MSGS / GOLDEN_OPS))
+    assert not {"sleep_share", "msgs_per_op"} & set(r.stats)
+    assert [(c.q_cap, c.bank_last) for c in rep.chunks] == [(4, 0)]
+    assert [(c["q_cap"], c["bank_last"])
+            for c in rep.to_dict()["chunks"]] == [(4, 0)]
+
+
 def test_failed_chunk_records_an_isolate_span(monkeypatch):
     orig = sweep_mod._sweep_group
 

@@ -98,7 +98,13 @@ def test_engine_lowers_every_protocol(protocol, tpu_params, topo):
     dict(protocol="colibri_hier", n_cores=1024, n_addrs=4096,
          topology="cluster2", clusters=4, workload="zipf_histogram",
          zipf_skew=100),
-], ids=["multi_tile", "cluster2_1024", "colibri_4096", "terapool_4096banks"])
+    # the terapool1024_4096banks_colibri cell: banks-first (4096, 1024)
+    # queue state in 16 tiles of (256, 1024)
+    dict(protocol="colibri", n_cores=1024, n_addrs=4096,
+         topology="cluster2", clusters=4, workload="zipf_histogram",
+         zipf_skew=100),
+], ids=["multi_tile", "cluster2_1024", "colibri_4096", "terapool_4096banks",
+        "terapool_4096banks_colibri"])
 def test_engine_lowers_at_scale(shape, tpu_params, topo):
     from jax.sharding import SingleDeviceSharding
     _assert_kernel(_compile_run(tpu_params(**shape),
